@@ -69,11 +69,11 @@ def main() -> None:
         opts = dict(reorder="auto", predictor="analytic", threads=8)
 
         cold = _time(lambda: cache.get_or_compile(csr, **opts)
-                     .execute(x, interpret=True))
+                     .execute(x))
         p = cache.get_or_compile(csr, **opts)        # cache hit
 
         warm = float(np.median([
-            _time(lambda: p.execute(x, interpret=True))
+            _time(lambda: p.execute(x))
             for _ in range(REPEATS)]))
 
         X = jnp.stack([x] * REPEATS)

@@ -36,6 +36,9 @@ def main(argv=None) -> None:
                          "resume from whatever is already committed there")
     args = ap.parse_args(argv)
 
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
+
     from . import common
     if args.fast:
         common.EMPIRICAL_MAX_LOG2 = 16

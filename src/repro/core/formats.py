@@ -242,32 +242,29 @@ class BELL:
                  blocks_per_row: int | None = None) -> "BELL":
         nbr = -(-csr.n_rows // bm)
         nbc = -(-csr.n_cols // bn)
-        indptr = np.asarray(csr.indptr)
-        cols = np.asarray(csr.indices)
+        indptr = np.asarray(csr.indptr, dtype=np.int64)
+        cols = np.asarray(csr.indices, dtype=np.int64)
         vals = np.asarray(csr.data)
-        # bucket nonzeros by (block_row, block_col)
-        from collections import defaultdict
-        buckets: dict = defaultdict(list)
-        for r in range(csr.n_rows):
-            br = r // bm
-            for p in range(int(indptr[r]), int(indptr[r + 1])):
-                c = int(cols[p])
-                buckets[(br, c // bn)].append((r % bm, c % bn, vals[p]))
-        per_row: dict = defaultdict(list)
-        for (br, bc), entries in buckets.items():
-            per_row[br].append((bc, entries))
-        width = blocks_per_row or max(
-            (len(v) for v in per_row.values()), default=1)
+        rows = np.repeat(np.arange(csr.n_rows, dtype=np.int64),
+                         np.diff(indptr))
+        # bucket nonzeros by (block_row, block_col); a block row's blocks
+        # are numbered in ascending block-column order
+        keys, blk = np.unique((rows // bm) * nbc + cols // bn,
+                              return_inverse=True)
+        blk = blk.reshape(-1)
+        kbr, kbc = keys // nbc, keys % nbc
+        first = np.searchsorted(kbr, np.arange(nbr))
+        kslot = np.arange(keys.size) - first[kbr]
+        width = blocks_per_row or (
+            int(np.bincount(kbr, minlength=1).max()) if keys.size else 1)
         width = max(width, 1)
         data = np.zeros((nbr, width, bm, bn), dtype=vals.dtype)
         bcols = np.zeros((nbr, width), dtype=np.int32)
-        for br, blocks in per_row.items():
-            blocks.sort(key=lambda t: t[0])
-            for k, (bc, entries) in enumerate(blocks[:width]):
-                bcols[br, k] = bc
-                for (ri, ci, v) in entries:
-                    data[br, k, ri, ci] += v
-        del nbc
+        keep = kslot < width
+        bcols[kbr[keep], kslot[keep]] = kbc[keep]
+        nz = keep[blk]
+        np.add.at(data, (rows[nz] // bm, kslot[blk[nz]], rows[nz] % bm,
+                         cols[nz] % bn), vals[nz])
         return BELL(
             data=jnp.asarray(data),
             block_cols=jnp.asarray(bcols),
@@ -316,9 +313,7 @@ class DIA:
         offs = cols.astype(np.int64) - rows
         uniq = np.unique(offs)
         data = np.zeros((len(uniq), csr.n_rows), dtype=vals.dtype)
-        pos = {int(o): k for k, o in enumerate(uniq)}
-        for r, c, v in zip(rows, cols, vals):
-            data[pos[int(c) - int(r)], r] += v
+        np.add.at(data, (np.searchsorted(uniq, offs), rows), vals)
         return DIA(
             data=jnp.asarray(data),
             offsets=jnp.asarray(uniq.astype(np.int32)),

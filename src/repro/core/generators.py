@@ -29,7 +29,8 @@ def fd_matrix(n_rows: int, dtype=np.float32, seed: int = 0) -> CSR:
     close to n_rows; we use exactly n_rows = g*g when possible, otherwise a
     g x h grid with g*h == n_rows (h = n_rows // g).  Every row has exactly
     nine nonzeros: itself and its eight (periodic) grid neighbours, which
-    yields the paper's three bands of three adjacent elements.
+    yields the paper's three bands of three adjacent elements (fewer on
+    a grid with a side under 3, where periodic neighbours coincide).
     """
     g = int(np.sqrt(n_rows))
     while n_rows % g != 0:
@@ -49,6 +50,13 @@ def fd_matrix(n_rows: int, dtype=np.float32, seed: int = 0) -> CSR:
     rows = np.concatenate(rows)
     cols = np.concatenate(cols)
     vals = rng.uniform(0.5, 1.5, size=rows.shape[0]).astype(dtype)
+    if min(g, h) < 3:
+        # a grid side under 3 wraps neighbours onto each other: merge the
+        # repeated coordinates so the CSR stays duplicate-free
+        keys, inv = np.unique(rows * n_rows + cols, return_inverse=True)
+        merged = np.zeros(keys.size, dtype=dtype)
+        np.add.at(merged, inv, vals)
+        rows, cols, vals = keys // n_rows, keys % n_rows, merged
     return CSR.from_coo(rows, cols, vals, n_rows, n_rows, dtype=dtype)
 
 

@@ -143,8 +143,6 @@ def spmv(matrix, x: jax.Array, use_pallas: bool = False,
     if use_pallas:
         from repro import plan as _plan
         from repro.kernels import ops as kops
-        if interpret is None:
-            interpret = jax.default_backend() != "tpu"
         if isinstance(matrix, (CSR, ELL, BELL, DIA, HYB)):
             if _plan.is_concrete(matrix):
                 p = _plan.DEFAULT_CACHE.get_or_build(

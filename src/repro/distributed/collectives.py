@@ -26,13 +26,6 @@ from jax.sharding import Mesh, PartitionSpec as P
 from repro.optim.grad_compress import crosspod_allreduce_compressed  # noqa: F401
 
 
-def _axis_size(axis_name: str) -> int:
-    # jax.lax.axis_size is newer jax; psum of 1 is the portable spelling
-    if hasattr(jax.lax, "axis_size"):
-        return jax.lax.axis_size(axis_name)
-    return jax.lax.psum(1, axis_name)
-
-
 def ring_allgather_matmul(x: jax.Array, w_shard: jax.Array, axis_name: str
                           ) -> jax.Array:
     """Inside shard_map: y = x @ all_gather(w, axis) without a blocking
@@ -42,7 +35,7 @@ def ring_allgather_matmul(x: jax.Array, w_shard: jax.Array, axis_name: str
     ppermute-ing shards around the ring -- compute hides the permute
     latency (XLA overlaps independent ops).
     """
-    n = _axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     idx = jax.lax.axis_index(axis_name)
     d_in = x.shape[-1]
     chunk = d_in // n
@@ -95,7 +88,7 @@ def lse_merge_attention(q: jax.Array, k_shard: jax.Array, v_shard: jax.Array,
 
 def reduce_scatter_grads(grads, axis_name: str):
     """ZeRO-2: each worker keeps 1/n of the (summed) gradient."""
-    n = _axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     idx = jax.lax.axis_index(axis_name)
 
     def one(g):

@@ -25,12 +25,11 @@ from jax.sharding import Mesh, PartitionSpec
 
 from repro.core.formats import CSR
 from repro.core.partition import RowPartition, rowblock_equal
+from repro.kernels import resolve_interpret
 from repro.kernels import spmv_ell as _ell
 from repro.kernels._layout import (ShardedELL, round_up,           # noqa: F401
                                    prepare_ell_shards)  # re-exported for
                                                         # pre-plan callers
-
-from .compat import shard_map
 
 _AXIS = "shards"
 
@@ -92,13 +91,14 @@ def spmv_row_sharded(csr: CSR, x: jax.Array, mesh: Optional[Mesh] = None,
     return p.execute(x, interpret=interpret)
 
 
-def spmv_row_sharded_prepared(prep: ShardedELL, x: jax.Array, mesh: Mesh,
-                              interpret: Optional[bool] = None) -> jax.Array:
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+def row_sharded_program(prep: ShardedELL, mesh: Mesh,
+                        interpret: Optional[bool] = None):
+    """The jitted `shard_map` program behind `spmv_row_sharded_prepared`:
+    `(prep.data, prep.idx, x_padded) -> (parts, rows_pad)` y slabs, one
+    slab per device on the mesh's 'shards' axis."""
+    interpret = resolve_interpret(interpret)
     bm = prep.bm
     _, rows_pad, w = prep.data.shape
-    xp = jnp.pad(x, (0, round_up(prep.n_cols, 128) - prep.n_cols))
 
     def one_shard(data, idx, xv):
         # data/idx arrive as this device's (1, rows_pad, w) slab
@@ -108,14 +108,24 @@ def spmv_row_sharded_prepared(prep: ShardedELL, x: jax.Array, mesh: Mesh,
                                  xv, interpret=interpret)
         return y.reshape(1, rows_pad)
 
-    sharded = shard_map(
+    return jax.jit(jax.shard_map(
         one_shard, mesh=mesh,
         in_specs=(PartitionSpec(_AXIS, None, None),
                   PartitionSpec(_AXIS, None, None),
                   PartitionSpec()),
         out_specs=PartitionSpec(_AXIS, None),
-        check_vma=False)
-    y_slabs = jax.jit(sharded)(prep.data, prep.idx, xp)   # (parts, rows_pad)
+        check_vma=False))
+
+
+def pad_x(prep: ShardedELL, x: jax.Array) -> jax.Array:
+    """x padded to the lane multiple every shard's column indices fit."""
+    return jnp.pad(x, (0, round_up(prep.n_cols, 128) - prep.n_cols))
+
+
+def spmv_row_sharded_prepared(prep: ShardedELL, x: jax.Array, mesh: Mesh,
+                              interpret: Optional[bool] = None) -> jax.Array:
+    y_slabs = row_sharded_program(prep, mesh, interpret)(
+        prep.data, prep.idx, pad_x(prep, x))              # (parts, rows_pad)
     parts = [y_slabs[p, : int(prep.starts[p + 1] - prep.starts[p])]
              for p in range(y_slabs.shape[0])]
     return jnp.concatenate(parts)[: prep.n_rows]

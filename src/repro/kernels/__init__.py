@@ -1,8 +1,9 @@
 """Pallas TPU kernels for the paper's compute hot spots.
 
   spmv_dia         banded SpMV (FD fast path): pure streaming, no gathers
-  spmv_ell         fixed-width ELL: dense tiles, whole x pinned in VMEM
-  spmv_csr         column-blocked CSR: x stripes pinned in VMEM (paper P2+P3)
+  spmv_ell         fixed-width ELL: x gathered by XLA, streamed row blocks
+  spmv_csr         column-blocked CSR: one-hot segment sum per row block
+  spmv_csr_seg     nnz-balanced segmented CSR (merge-CSR)
   spmv_bell        blocked-ELL: data-dependent block-tile gathers (paper P3)
   _layout          shared host-side layout prep: `prepare_*` (run once,
                    at plan-compile time) + `spmv_*_prepared` (zero
@@ -12,6 +13,38 @@
   flash_attention  causal + sliding-window (banded) attention
   paged_attention  decode over block-table KV (BELL pattern on the cache)
 
-Validated with interpret=True on CPU against the jnp oracles in ref.py;
-compiled by Mosaic on real TPUs.
+Every kernel takes `interpret=None` and resolves it through
+`resolve_interpret`: compiled by Mosaic on a TPU backend, interpret mode
+everywhere else (validated on CPU against the jnp oracles in ref.py).
 """
+from __future__ import annotations
+
+from typing import Optional
+
+import jax
+
+
+def resolve_interpret(interpret: Optional[bool] = None) -> bool:
+    """Whether Pallas kernels run in interpret mode, decided by the backend.
+
+    None means "what the backend supports": Mosaic on a TPU, the
+    interpreter elsewhere.  Interpreting on a TPU would hide the device
+    behind a Python emulation, so asking for it there raises."""
+    on_tpu = jax.default_backend() == "tpu"
+    if interpret is None:
+        return not on_tpu
+    if interpret and on_tpu:
+        raise ValueError("interpret=True on a TPU backend: Pallas kernels "
+                         "run compiled by Mosaic there; pass interpret=None")
+    return bool(interpret)
+
+
+def gather_x(x: jax.Array, idx: jax.Array) -> jax.Array:
+    """x[idx] as one XLA gather from HBM, for the kernels Mosaic cannot
+    gather in (ELL, CSR, CSR-seg).  Layouts build every index in range,
+    so the gather skips jnp's negative-index wrap, an extra elementwise
+    pass over `idx`."""
+    dnums = jax.lax.GatherDimensionNumbers(
+        offset_dims=(), collapsed_slice_dims=(0,), start_index_map=(0,))
+    return jax.lax.gather(x, idx[..., None], dnums, slice_sizes=(1,),
+                          mode=jax.lax.GatherScatterMode.PROMISE_IN_BOUNDS)

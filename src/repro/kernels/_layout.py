@@ -10,10 +10,16 @@ work per call -- only the per-call x pad/reshape plus the Pallas kernel.
 This split is what `repro.plan` builds on: `prepare_*` runs at plan-compile
 time, `spmv_*_prepared` is the amortized hot path.  The per-call wrappers in
 `kernels.ops` are now just `prepare_*` + `spmv_*_prepared` composed.
+
+The `Prepared*` containers are pytrees (arrays are leaves, sizes and
+knobs are static), so each runner is one jitted program taking the layout
+as an argument: the x pad, the XLA gather, the kernel and the merge fuse
+into one executable, shared by every plan of the same shapes.
 """
 from __future__ import annotations
 
 import dataclasses
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -36,10 +42,27 @@ def round_up(v: int, m: int) -> int:
     return ceil_div(v, m) * m
 
 
+def _pytree(*data_fields: str):
+    """Register a frozen layout dataclass as a pytree whose `data_fields`
+    are leaves and every other field is static metadata."""
+    def register(cls):
+        meta = [f.name for f in dataclasses.fields(cls)
+                if f.name not in data_fields]
+        return jax.tree_util.register_dataclass(
+            cls, data_fields=list(data_fields), meta_fields=meta)
+    return register
+
+
+_runner = functools.partial(jax.jit, static_argnames=("interpret",
+                                                      "semiring"))
+_runner_plus_times = functools.partial(jax.jit, static_argnames=("interpret",))
+
+
 # ---------------------------------------------------------------------------
 # DIA
 # ---------------------------------------------------------------------------
 
+@_pytree("band", "offsets")
 @dataclasses.dataclass(frozen=True)
 class PreparedDIA:
     """Pre-padded banded layout: band rows padded to a bn multiple."""
@@ -65,8 +88,9 @@ def prepare_dia(dia: DIA, bn: int = 512) -> PreparedDIA:
                        n_cols=dia.n_cols, bn=bn)
 
 
+@_runner_plus_times
 def spmv_dia_prepared(prep: PreparedDIA, x: jax.Array,
-                      interpret: bool = True) -> jax.Array:
+                      interpret: bool | None = None) -> jax.Array:
     xp = jnp.pad(x, (0, prep.band.shape[1] - x.shape[0]))
     y = _dia.spmv_dia_pallas(prep.band, prep.offsets, xp, bn=prep.bn,
                              interpret=interpret)
@@ -77,6 +101,7 @@ def spmv_dia_prepared(prep: PreparedDIA, x: jax.Array,
 # BELL (already kernel-shaped; prep only records the x pad width)
 # ---------------------------------------------------------------------------
 
+@_pytree("data", "block_cols")
 @dataclasses.dataclass(frozen=True)
 class PreparedBELL:
     data: jax.Array        # (nbr, bpr, bm, bn)
@@ -93,8 +118,9 @@ def prepare_bell(bell: BELL) -> PreparedBELL:
                         x_pad=nbc * bell.bn)
 
 
+@_runner_plus_times
 def spmv_bell_prepared(prep: PreparedBELL, x: jax.Array,
-                       interpret: bool = True) -> jax.Array:
+                       interpret: bool | None = None) -> jax.Array:
     xp = jnp.pad(x, (0, prep.x_pad - prep.n_cols))
     y = _bell.spmv_bell_pallas(prep.data, prep.block_cols, xp,
                                interpret=interpret)
@@ -105,6 +131,7 @@ def spmv_bell_prepared(prep: PreparedBELL, x: jax.Array,
 # ELL (row-blocked, fixed width)
 # ---------------------------------------------------------------------------
 
+@_pytree("data", "idx")
 @dataclasses.dataclass(frozen=True)
 class PreparedELL:
     """Row-blocked (B, bm, W) ELL arrays; padding rows index col 0 val 0."""
@@ -137,8 +164,10 @@ def prepare_ell(ell: ELL, bm: int = 128, pad_mult: int = 128,
         x_pad=round_up(ell.n_cols, pad_mult))
 
 
+@_runner
 def spmv_ell_prepared(prep: PreparedELL, x: jax.Array,
-                      interpret: bool = True, semiring=None) -> jax.Array:
+                      interpret: bool | None = None,
+                      semiring=None) -> jax.Array:
     xp = jnp.pad(x, (0, prep.x_pad - prep.n_cols))
     y = _ell.spmv_ell_pallas(prep.data, prep.idx, xp, interpret=interpret,
                              semiring=semiring)
@@ -196,6 +225,7 @@ def prepare_ell_shards(csr: CSR, partition, bm: int = 128,
 # CSR (column-blocked, padded)
 # ---------------------------------------------------------------------------
 
+@_pytree("vals", "cols", "rowin")
 @dataclasses.dataclass(frozen=True)
 class PaddedCSR:
     """Host-prepped column-blocked layout for the spmv_csr kernel."""
@@ -248,8 +278,10 @@ def prepare_csr(csr: CSR, n_stripes: int = 1, bm: int = 128,
     )
 
 
+@_runner
 def spmv_csr_prepared(prep: PaddedCSR, x: jax.Array,
-                      interpret: bool = True, semiring=None) -> jax.Array:
+                      interpret: bool | None = None,
+                      semiring=None) -> jax.Array:
     s_dim = prep.vals.shape[0]
     xp = jnp.pad(x, (0, s_dim * prep.stripe_w - prep.n_cols))
     x_stripes = xp.reshape(s_dim, prep.stripe_w)
@@ -267,6 +299,7 @@ def spmv_csr_prepared(prep: PaddedCSR, x: jax.Array,
 # Segmented CSR (nnz-balanced flat stream; the merge-CSR layout)
 # ---------------------------------------------------------------------------
 
+@_pytree("vals", "cols", "rid", "row_ids", "nonempty")
 @dataclasses.dataclass(frozen=True)
 class PreparedSegCSR:
     """Flat nonzero stream cut into equal-nnz segments for spmv_csr_seg.
@@ -338,8 +371,10 @@ def prepare_csr_seg(csr: CSR, seg_len: int = 512, pad_mult: int = 128,
         x_pad=round_up(max(csr.n_cols, 1), pad_mult))
 
 
+@_runner
 def spmv_csr_seg_prepared(prep: PreparedSegCSR, x: jax.Array,
-                          interpret: bool = True, semiring=None) -> jax.Array:
+                          interpret: bool | None = None,
+                          semiring=None) -> jax.Array:
     xp = jnp.pad(x, (0, prep.x_pad - prep.n_cols))
     partials = _seg.spmv_csr_seg_pallas(prep.vals, prep.cols, prep.rid, xp,
                                         rwin=prep.rwin, interpret=interpret,
@@ -362,6 +397,7 @@ def spmv_csr_seg_prepared(prep: PreparedSegCSR, x: jax.Array,
 # HYB (ELL light partition + column-sorted COO heavy tail)
 # ---------------------------------------------------------------------------
 
+@_pytree("light", "heavy")
 @dataclasses.dataclass(frozen=True)
 class PreparedHYB:
     """Two fused launches per SpMV: the ELL kernel over the light rows
@@ -395,8 +431,10 @@ def prepare_hyb(hyb: HYB, seg_len: int = 512, bm: int = 128,
                        n_cols=hyb.n_cols)
 
 
+@_runner
 def spmv_hyb_prepared(prep: PreparedHYB, x: jax.Array,
-                      interpret: bool = True, semiring=None) -> jax.Array:
+                      interpret: bool | None = None,
+                      semiring=None) -> jax.Array:
     y_light = spmv_ell_prepared(prep.light, x, interpret=interpret,
                                 semiring=semiring)
     y_heavy = spmv_csr_seg_prepared(prep.heavy, x, interpret=interpret,

@@ -18,7 +18,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .compat import CompilerParams
+from . import resolve_interpret
 
 NEG_INF = -1e30
 
@@ -88,7 +88,7 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
 def flash_attention_pallas(q: jax.Array, k: jax.Array, v: jax.Array,
                            causal: bool = True, window: int | None = None,
                            bq: int = 128, bk: int = 128,
-                           interpret: bool = True) -> jax.Array:
+                           interpret: bool | None = None) -> jax.Array:
     """q: (bh, sq, d), k/v: (bh, skv, d) -> (bh, sq, d).
 
     `window`: sliding-window size (None = full attention).  fp32 accumulate.
@@ -117,8 +117,8 @@ def flash_attention_pallas(q: jax.Array, k: jax.Array, v: jax.Array,
             pltpu.VMEM((bq, 128), jnp.float32),   # running denom l
             pltpu.VMEM((bq, d), jnp.float32),     # output accumulator
         ],
-        interpret=interpret,
-        compiler_params=CompilerParams(
+        interpret=resolve_interpret(interpret),
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
     )(q, k, v)

@@ -58,12 +58,13 @@ def _reordered(kernel_fn):
 
 @_reordered
 def spmv_dia(dia: DIA, x: jax.Array, bn: int = 512,
-             interpret: bool = True) -> jax.Array:
+             interpret: bool | None = None) -> jax.Array:
     return spmv_dia_prepared(prepare_dia(dia, bn=bn), x, interpret=interpret)
 
 
 @_reordered
-def spmv_bell(bell: BELL, x: jax.Array, interpret: bool = True) -> jax.Array:
+def spmv_bell(bell: BELL, x: jax.Array,
+              interpret: bool | None = None) -> jax.Array:
     return spmv_bell_prepared(prepare_bell(bell), x, interpret=interpret)
 
 
@@ -92,7 +93,7 @@ def _check_ell_padding_absorbing(ell: ELL, semiring) -> None:
 
 @_reordered
 def spmv_ell(ell: ELL, x: jax.Array, bm: int = 128,
-             interpret: bool = True, semiring=None) -> jax.Array:
+             interpret: bool | None = None, semiring=None) -> jax.Array:
     """Row-block the (n_rows, max_nnz) ELL arrays to (B, bm, W) and run the
     Pallas kernel; padding rows index col 0 with the absorbing pad value
     (0 for the default plus-times `semiring`).
@@ -110,7 +111,7 @@ def spmv_ell(ell: ELL, x: jax.Array, bm: int = 128,
 
 @_reordered
 def spmv_csr(csr: CSR, x: jax.Array, n_stripes: int = 1,
-             interpret: bool = True, semiring=None) -> jax.Array:
+             interpret: bool | None = None, semiring=None) -> jax.Array:
     """Convenience wrapper: preps layout per call (compile a
     `repro.plan.SpmvPlan` to cache the `PaddedCSR` for repeated
     multiplies)."""
@@ -122,7 +123,7 @@ def spmv_csr(csr: CSR, x: jax.Array, n_stripes: int = 1,
 
 @_reordered
 def spmv_csr_seg(csr: CSR, x: jax.Array, seg_len: int = 512,
-                 interpret: bool = True, semiring=None) -> jax.Array:
+                 interpret: bool | None = None, semiring=None) -> jax.Array:
     """nnz-balanced segmented (merge) CSR: equal-nonzero segments over a
     static grid with a carry-out merge across segment boundaries.
     Convenience wrapper; compile a `repro.plan.SpmvPlan` with
@@ -154,7 +155,7 @@ def _check_hyb_padding_absorbing(hyb: HYB, semiring) -> None:
 
 @_reordered
 def spmv_hyb(hyb: HYB, x: jax.Array, seg_len: int = 512,
-             interpret: bool = True, semiring=None) -> jax.Array:
+             interpret: bool | None = None, semiring=None) -> jax.Array:
     """Hybrid row split: one ELL launch over the light rows, one
     segmented launch over the column-sorted heavy stream, joined by ⊕.
     Non-plus-times semirings require the container's light padding to be
@@ -174,7 +175,7 @@ def spmv_hyb(hyb: HYB, x: jax.Array, seg_len: int = 512,
 
 def paged_attention(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
                     tables: jax.Array, lengths: jax.Array,
-                    interpret: bool = True) -> jax.Array:
+                    interpret: bool | None = None) -> jax.Array:
     """q: (B, H, hd); pools: (n_blocks, block, KVH, hd) with KVH | H;
     tables: (B, max_blocks) int32; lengths: (B,) -> (B, H, hd)."""
     from . import paged_attention as _pa
@@ -195,7 +196,7 @@ def paged_attention(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
 
 def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                     causal: bool = True, window: int | None = None,
-                    interpret: bool = True) -> jax.Array:
+                    interpret: bool | None = None) -> jax.Array:
     """q/k/v: (batch, heads, seq, head_dim); GQA callers broadcast kv first."""
     b, h, sq, d = q.shape
     skv = k.shape[2]

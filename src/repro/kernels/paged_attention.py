@@ -31,7 +31,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .compat import CompilerParams
+from . import resolve_interpret
 
 NEG_INF = -1e30
 
@@ -84,7 +84,7 @@ def _kernel(tables_ref, lengths_ref, q_ref, k_ref, v_ref, o_ref,
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def paged_attention_pallas(q, k_pool, v_pool, tables, lengths,
-                           interpret: bool = True):
+                           interpret: bool | None = None):
     """q: (B, H, hd); pools: (n_blocks, block, H, hd) (GQA pre-broadcast);
     tables: (B, max_blocks) int32; lengths: (B,) int32 -> (B, H, hd)."""
     bsz, h, hd = q.shape
@@ -122,8 +122,8 @@ def paged_attention_pallas(q, k_pool, v_pool, tables, lengths,
             ],
         ),
         out_shape=jax.ShapeDtypeStruct((bsz, h, hd), q.dtype),
-        interpret=interpret,
-        compiler_params=CompilerParams(
+        interpret=resolve_interpret(interpret),
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
         ),
     )(tables.astype(jnp.int32), lengths.astype(jnp.int32), q, kp, vp)

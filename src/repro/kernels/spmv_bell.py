@@ -9,9 +9,12 @@ tile, chosen by a scalar-prefetched block-column index -- the paper's P3
 
 Layout:
   data       : (n_block_rows, blocks_per_row, bm, bn)  dense blocks
-  block_cols : (n_block_rows, blocks_per_row) int32     scalar-prefetched
-  x tiles    : (n_col_blocks, bn)
-  y          : (n_block_rows, bm)
+  block_cols : (n_block_rows * blocks_per_row,) int32  scalar-prefetched,
+                                                       flat (a 2-D SMEM
+                                                       array pads its last
+                                                       dim to 128 words)
+  x tiles    : (n_col_blocks, 1, bn)
+  y          : (n_block_rows, 1, bm)
 
 Grid = (n_block_rows, blocks_per_row); the x tile index_map dereferences
 block_cols -- a data-dependent DMA schedule, which is exactly the
@@ -27,7 +30,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .compat import CompilerParams
+from . import resolve_interpret
 
 
 def _kernel(bc_ref, data_ref, x_ref, out_ref):
@@ -38,17 +41,18 @@ def _kernel(bc_ref, data_ref, x_ref, out_ref):
         out_ref[...] = jnp.zeros_like(out_ref)
 
     block = data_ref[0, 0]                       # (bm, bn)
-    tile = x_ref[0, :]                           # (bn,)
-    out_ref[0, :] += jax.lax.dot_general(
-        block, tile[:, None],
-        (((1,), (0,)), ((), ())),
+    tile = x_ref[0]                              # (1, bn)
+    out_ref[0] += jax.lax.dot_general(           # (1, bm) = tile @ block.T
+        tile, block,
+        (((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32,
-    )[:, 0].astype(out_ref.dtype)
+        precision=jax.lax.Precision.HIGHEST,
+    ).astype(out_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def spmv_bell_pallas(data: jax.Array, block_cols: jax.Array, x: jax.Array,
-                     interpret: bool = True) -> jax.Array:
+                     interpret: bool | None = None) -> jax.Array:
     """y = A @ x for A in blocked-ELL layout.
 
     data       : (nbr, bpr, bm, bn)
@@ -58,7 +62,7 @@ def spmv_bell_pallas(data: jax.Array, block_cols: jax.Array, x: jax.Array,
     """
     nbr, bpr, bm, bn = data.shape
     assert x.shape[0] % bn == 0
-    x_tiles = x.reshape(-1, bn)
+    x_tiles = x.reshape(-1, 1, bn)
 
     out = pl.pallas_call(
         _kernel,
@@ -67,14 +71,15 @@ def spmv_bell_pallas(data: jax.Array, block_cols: jax.Array, x: jax.Array,
             grid=(nbr, bpr),
             in_specs=[
                 pl.BlockSpec((1, 1, bm, bn), lambda b, k, bc: (b, k, 0, 0)),
-                pl.BlockSpec((1, bn), lambda b, k, bc: (bc[b, k], 0)),
+                pl.BlockSpec((1, 1, bn),
+                             lambda b, k, bc: (bc[b * bpr + k], 0, 0)),
             ],
-            out_specs=pl.BlockSpec((1, bm), lambda b, k, bc: (b, 0)),
+            out_specs=pl.BlockSpec((1, 1, bm), lambda b, k, bc: (b, 0, 0)),
         ),
-        out_shape=jax.ShapeDtypeStruct((nbr, bm), data.dtype),
-        interpret=interpret,
-        compiler_params=CompilerParams(
+        out_shape=jax.ShapeDtypeStruct((nbr, 1, bm), data.dtype),
+        interpret=resolve_interpret(interpret),
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
         ),
-    )(block_cols.astype(jnp.int32), data, x_tiles)
+    )(block_cols.reshape(-1).astype(jnp.int32), data, x_tiles)
     return out.reshape(-1)

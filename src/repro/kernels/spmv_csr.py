@@ -1,26 +1,26 @@
 """Column-blocked padded-CSR SpMV Pallas kernel -- the paper's P2+P3.
 
-This is the software realization of the paper's proposed architecture fixes:
-partition A into column stripes whose x slice fits VMEM, *pin* the slice
-(P2: dedicate cache to x), and let the row-pointer metadata drive the DMA
-schedule (P3: kernel-directed placement).  The matrix arrays stream exactly
-once (P1: no cache to pollute).
+The software realization of the paper's proposed architecture fixes:
+partition A into column stripes (P2: bound the x working set) and let the
+row-pointer metadata drive the schedule (P3: kernel-directed placement).
+The matrix arrays stream exactly once (P1: no cache to pollute).
 
-Host-side prep (ops.py) pads each (row_block x stripe) cell to a fixed
-nonzero count W so shapes are static:
+Host-side prep (`_layout.prepare_csr`) pads each (row_block x stripe)
+cell to a fixed nonzero count W so shapes are static:
 
-  vals  : (S, B, W)  f32   padding value 0.0
+  vals  : (S, B, W)  f32   padding value 0.0 (or the semiring's pad)
   cols  : (S, B, W)  int32 stripe-rebased column, padding 0
   rowin : (S, B, W)  int32 row-within-block, padding 0
 
-Grid = (S, B) with the stripe dimension OUTER so the x stripe block index is
-constant across the inner sweep -- Mosaic keeps it resident in VMEM (the
-"pin").  Each (s, b) cell writes a partial y block; a cheap dense reduction
-over S finishes the sum (the y-spill term of core.traffic.col_blocked_policy).
+Mosaic has no general in-kernel gather, so x is gathered by XLA outside
+the kernel and streams in as one more (S, B, W) input under the same
+block as the values.  Grid = (S, B); each (s, b) cell writes a partial y
+block and a dense reduction over S finishes the sum (the y-spill term of
+core.traffic.col_blocked_policy).
 
-In-kernel accumulation uses a one-hot matmul (rows x W @ W) so the segment
-sum runs on the MXU instead of a scatter -- scatters don't exist in the TPU
-memory model.
+In-kernel accumulation uses a one-hot matmul (1 x W @ W x bm) so the
+segment sum runs on the MXU instead of a scatter -- scatters don't exist
+in the TPU memory model.
 """
 from __future__ import annotations
 
@@ -31,36 +31,38 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .compat import CompilerParams
+from . import gather_x, resolve_interpret
 
 
-def _kernel(vals_ref, cols_ref, rowin_ref, x_ref, part_ref, *, bm,
-            semiring=None):
-    xg = jnp.take(x_ref[0, :], cols_ref[0, 0, :], axis=0)      # VMEM gather
-    rows = rowin_ref[0, 0, :]                                  # (W,)
-    onehot = (jax.lax.broadcasted_iota(jnp.int32, (bm, rows.shape[0]), 0)
-              == rows[None, :])
+def segment_reduce(vals, xg, ranks, rwin: int, semiring=None):
+    """(1, W) values, gathered x and segment ranks -> (1, rwin) partials:
+    out[r] = ⊕ over slots s with ranks[s] == r of vals[s] ⊗ xg[s]."""
+    onehot = (jax.lax.broadcasted_iota(jnp.int32, (rwin, ranks.shape[1]), 0)
+              == ranks)                                        # (rwin, W)
     if semiring is None:                                       # plus-times
-        prods = vals_ref[0, 0, :] * xg                         # (W,)
-        part_ref[0, 0, :] = jax.lax.dot_general(
-            onehot.astype(prods.dtype), prods[:, None],
-            (((1,), (0,)), ((), ())),
+        return jax.lax.dot_general(                            # MXU
+            vals * xg, onehot.astype(vals.dtype),
+            (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32,
-        )[:, 0].astype(part_ref.dtype)
-    else:
-        # generalized segment-⊕: min/max have no matmul form, so select
-        # each row's slots with the same one-hot mask (identity
-        # elsewhere) and ⊕-reduce on the VPU instead of the MXU.
-        prods = semiring.mul(vals_ref[0, 0, :], xg)            # (W,)
-        masked = jnp.where(onehot, prods[None, :],
-                           jnp.asarray(semiring.identity, prods.dtype))
-        part_ref[0, 0, :] = semiring.reduce(masked,
-                                            axis=1).astype(part_ref.dtype)
+            precision=jax.lax.Precision.HIGHEST,
+        )
+    # generalized segment-⊕: min/max have no matmul form, so select each
+    # rank's slots with the same one-hot mask (identity elsewhere) and
+    # ⊕-reduce on the VPU instead of the MXU.
+    masked = jnp.where(onehot, semiring.mul(vals, xg),
+                       jnp.asarray(semiring.identity, vals.dtype))
+    return semiring.reduce(masked, axis=1).reshape(1, rwin)
+
+
+def _kernel(vals_ref, xg_ref, rowin_ref, part_ref, *, bm, semiring=None):
+    part_ref[0, 0] = segment_reduce(
+        vals_ref[0, 0], xg_ref[0, 0], rowin_ref[0, 0], bm,
+        semiring).astype(part_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret", "semiring"))
 def spmv_csr_pallas(vals: jax.Array, cols: jax.Array, rowin: jax.Array,
-                    x_stripes: jax.Array, interpret: bool = True,
+                    x_stripes: jax.Array, interpret: bool | None = None,
                     semiring=None) -> jax.Array:
     """Partial-product pass: returns (S, B, bm) partials; ⊕ over S outside.
 
@@ -73,23 +75,25 @@ def spmv_csr_pallas(vals: jax.Array, cols: jax.Array, rowin: jax.Array,
     if semiring is not None and semiring.name == "plus_times":
         semiring = None
     s_dim, b_dim, w = vals.shape
-    bm = 128  # rows per block (fixed by ops.py prep)
+    bm = 128  # rows per block (fixed by _layout.prepare_csr)
+    stripe_w = x_stripes.shape[1]
+    # XLA gather: each stripe's rebased columns index its own x stripe
+    base = (jnp.arange(s_dim, dtype=jnp.int32) * stripe_w)[:, None, None]
+    xg = gather_x(x_stripes.reshape(-1), cols + base)
 
+    def cells(a):                       # (S, B, W) -> (S, B, 1, W) blocks
+        return a.reshape(s_dim, b_dim, 1, w)
+
+    block = pl.BlockSpec((1, 1, 1, w), lambda s, b: (s, b, 0, 0))
     partials = pl.pallas_call(
         functools.partial(_kernel, bm=bm, semiring=semiring),
         grid=(s_dim, b_dim),
-        in_specs=[
-            pl.BlockSpec((1, 1, w), lambda s, b: (s, b, 0)),
-            pl.BlockSpec((1, 1, w), lambda s, b: (s, b, 0)),
-            pl.BlockSpec((1, 1, w), lambda s, b: (s, b, 0)),
-            # stripe pinned: block index depends only on the OUTER dim
-            pl.BlockSpec((1, x_stripes.shape[1]), lambda s, b: (s, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, 1, bm), lambda s, b: (s, b, 0)),
-        out_shape=jax.ShapeDtypeStruct((s_dim, b_dim, bm), vals.dtype),
-        interpret=interpret,
-        compiler_params=CompilerParams(
+        in_specs=[block, block, block],
+        out_specs=pl.BlockSpec((1, 1, 1, bm), lambda s, b: (s, b, 0, 0)),
+        out_shape=jax.ShapeDtypeStruct((s_dim, b_dim, 1, bm), vals.dtype),
+        interpret=resolve_interpret(interpret),
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
         ),
-    )(vals, cols, rowin, x_stripes)
-    return partials
+    )(cells(vals), cells(xg), cells(rowin))
+    return partials.reshape(s_dim, b_dim, bm)

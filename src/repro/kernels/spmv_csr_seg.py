@@ -15,7 +15,8 @@ Layout (host prep in `_layout.prepare_csr_seg`):
   cols : (S, L)  int32 column per nonzero, padding 0
   rid  : (S, L)  int32 LOCAL row rank within the segment (dense: 0..R-1 in
                        stream order), padding R-1
-  x    : (1, n_pad)    whole operand vector, block-constant -> pinned
+  xg   : (S, L)  f32   x[cols], gathered by XLA outside the kernel and
+                       streamed under the same block as the values
 
 Ranks are per-segment-dense rather than row offsets so the partial window
 R is bounded by L even when empty rows interleave; `row_ids[s, r]` (host
@@ -24,48 +25,32 @@ dummy row n_rows.  A row crossing segments s and s+1 appears as the last
 rank of s and rank 0 of s+1; the host-side segment-⊕ over `row_ids` is
 the merge that stitches those partials back together.
 
-In-segment accumulation reuses the one-hot matmul trick of `spmv_csr`
-(segment sum on the MXU; TPU has no scatter), or a masked ⊕-reduce on the
-VPU for non-plus-times semirings.
+In-segment accumulation reuses `spmv_csr.segment_reduce`: the one-hot
+matmul (segment sum on the MXU; TPU has no scatter), or a masked
+⊕-reduce on the VPU for non-plus-times semirings.
 """
 from __future__ import annotations
 
 import functools
 
 import jax
-import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from .compat import CompilerParams
+from . import gather_x, resolve_interpret
+from .spmv_csr import segment_reduce
 
 
-def _kernel(vals_ref, cols_ref, rid_ref, x_ref, part_ref, *, rwin,
-            semiring=None):
-    xg = jnp.take(x_ref[0, :], cols_ref[0, :], axis=0)         # VMEM gather
-    ranks = rid_ref[0, :]                                      # (L,)
-    onehot = (jax.lax.broadcasted_iota(jnp.int32, (rwin, ranks.shape[0]), 0)
-              == ranks[None, :])
-    if semiring is None:                                       # plus-times
-        prods = vals_ref[0, :] * xg                            # (L,)
-        part_ref[0, :] = jax.lax.dot_general(
-            onehot.astype(prods.dtype), prods[:, None],
-            (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )[:, 0].astype(part_ref.dtype)
-    else:
-        # generalized segment-⊕: mask each rank's slots (identity
-        # elsewhere) and ⊕-reduce on the VPU; absorbing pad slots
-        # contribute the identity wherever their rank lands.
-        prods = semiring.mul(vals_ref[0, :], xg)               # (L,)
-        masked = jnp.where(onehot, prods[None, :],
-                           jnp.asarray(semiring.identity, prods.dtype))
-        part_ref[0, :] = semiring.reduce(masked,
-                                         axis=1).astype(part_ref.dtype)
+def _kernel(vals_ref, xg_ref, rid_ref, part_ref, *, rwin, semiring=None):
+    # absorbing pad slots contribute the identity wherever their rank lands
+    part_ref[0] = segment_reduce(vals_ref[0], xg_ref[0], rid_ref[0], rwin,
+                                 semiring).astype(part_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("rwin", "interpret", "semiring"))
 def spmv_csr_seg_pallas(vals: jax.Array, cols: jax.Array, rid: jax.Array,
-                        x: jax.Array, rwin: int, interpret: bool = True,
+                        x: jax.Array, rwin: int,
+                        interpret: bool | None = None,
                         semiring=None) -> jax.Array:
     """Partial pass: returns (S, rwin) per-segment rank partials.
 
@@ -82,22 +67,21 @@ def spmv_csr_seg_pallas(vals: jax.Array, cols: jax.Array, rid: jax.Array,
     if semiring is not None and semiring.name == "plus_times":
         semiring = None                 # one compiled path, bit-identical
     s_dim, seg_len = vals.shape
-    xp = x.reshape(1, -1)
+    xg = gather_x(x, cols)
+
+    def segs(a):                        # (S, L) -> (S, 1, L) blocks
+        return a.reshape(s_dim, 1, seg_len)
+
+    block = pl.BlockSpec((1, 1, seg_len), lambda s: (s, 0, 0))
     partials = pl.pallas_call(
         functools.partial(_kernel, rwin=rwin, semiring=semiring),
         grid=(s_dim,),
-        in_specs=[
-            pl.BlockSpec((1, seg_len), lambda s: (s, 0)),
-            pl.BlockSpec((1, seg_len), lambda s: (s, 0)),
-            pl.BlockSpec((1, seg_len), lambda s: (s, 0)),
-            # whole x pinned: block index constant across the grid
-            pl.BlockSpec((1, xp.shape[1]), lambda s: (0, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, rwin), lambda s: (s, 0)),
-        out_shape=jax.ShapeDtypeStruct((s_dim, rwin), vals.dtype),
-        interpret=interpret,
-        compiler_params=CompilerParams(
+        in_specs=[block, block, block],
+        out_specs=pl.BlockSpec((1, 1, rwin), lambda s: (s, 0, 0)),
+        out_shape=jax.ShapeDtypeStruct((s_dim, 1, rwin), vals.dtype),
+        interpret=resolve_interpret(interpret),
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",),
         ),
-    )(vals, cols, rid, xp)
-    return partials
+    )(segs(vals), segs(xg), segs(rid))
+    return partials.reshape(s_dim, rwin)

@@ -13,14 +13,9 @@ import jax
 
 
 def make_mesh(shape, axes):
-    """`jax.make_mesh` across jax versions: newer jax wants explicit
-    `axis_types`; on older jax (no `jax.sharding.AxisType`) meshes are
-    Auto-typed already and the kwarg does not exist."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return jax.make_mesh(shape, axes)
+    """`jax.make_mesh` with every axis Auto-typed (GSPMD propagation)."""
     return jax.make_mesh(shape, axes,
-                         axis_types=(axis_type.Auto,) * len(axes))
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):

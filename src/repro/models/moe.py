@@ -22,7 +22,6 @@ import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
 from repro.distributed.api import constrain
-from repro.distributed.compat import shard_map
 from .common import dense_init, dtype_of
 
 Params = Dict[str, Any]
@@ -214,7 +213,7 @@ def apply_moe_sharded(p: Params, cfg: ModelConfig, x: jax.Array
             y = jax.lax.psum(y, "model")
         return y.astype(xs.dtype).reshape(bl, s, d), aux
 
-    shard = shard_map(
+    shard = jax.shard_map(
         local_fn, mesh=mesh,
         in_specs=(P(dp, None, None), P(None, None),
                   P("model", None, None), P("model", None, None),
@@ -318,7 +317,7 @@ def apply_moe_a2a(p: Params, cfg: ModelConfig, x: jax.Array
             gathered.astype(jnp.float32) * sw_[:, None])
         return y.astype(xs.dtype).reshape(bl, sl, d), aux
 
-    shard = shard_map(
+    shard = jax.shard_map(
         local_fn, mesh=mesh,
         in_specs=(P(dp, "model", None), P(None, None),
                   P("model", None, None), P("model", None, None),
@@ -417,7 +416,7 @@ def apply_moe_decode(p: Params, cfg: ModelConfig, x: jax.Array
         y = jax.lax.psum(y.astype(jnp.bfloat16), "model")
         return y.astype(xs.dtype).reshape(bl, s, d), aux
 
-    shard = shard_map(
+    shard = jax.shard_map(
         local_fn, mesh=mesh,
         in_specs=(P(None, None, None), P(None, None),
                   P("model", "data", None), P("model", "data", None),

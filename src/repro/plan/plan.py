@@ -36,9 +36,15 @@ import jax.numpy as jnp
 from repro.core.formats import BELL, CSR, DIA, ELL, HYB
 from repro.kernels import _layout as kl
 
-
-def _resolve_interpret(interpret: Optional[bool]) -> bool:
-    return jax.default_backend() != "tpu" if interpret is None else interpret
+# format name -> jitted `(prep, x, interpret=, [semiring=])` runner
+RUNNERS = {
+    "dia": kl.spmv_dia_prepared,
+    "bell": kl.spmv_bell_prepared,
+    "ell": kl.spmv_ell_prepared,
+    "csr": kl.spmv_csr_prepared,
+    "csr-seg": kl.spmv_csr_seg_prepared,
+    "hyb": kl.spmv_hyb_prepared,
+}
 
 
 def _jnp_kernels():
@@ -114,38 +120,41 @@ class SpmvPlan:
     def _run(self, x: jax.Array, interpret: Optional[bool]) -> jax.Array:
         if not self.use_pallas:
             return self._jnp_kernel()(x)
-        interpret = _resolve_interpret(
-            self.interpret if interpret is None else interpret)
-        sr = self._semiring()
         if self.format_name == "ell-sharded":
             from repro.distributed.spmv import spmv_row_sharded_prepared
-            if sr is not None:
+            if self._semiring() is not None:
                 raise ValueError("sharded plans are plus-times only")
             if self.mesh is None:
                 raise ValueError("sharded plan has no mesh bound; pass "
                                  "mesh= to load_plan or set plan.mesh")
-            return spmv_row_sharded_prepared(self.prep, x, self.mesh,
-                                             interpret=interpret)
+            return spmv_row_sharded_prepared(
+                self.prep, x, self.mesh,
+                interpret=self.interpret if interpret is None else interpret)
+        runner, kwargs = self._runner(interpret)
+        return runner(self.prep, x, **kwargs)
+
+    def _runner(self, interpret: Optional[bool]):
+        """The jitted kernel runner for this plan and its static kwargs."""
+        kwargs = {"interpret": self.interpret if interpret is None
+                  else interpret}
+        sr = self._semiring()
         if sr is not None:
             if self.format_name not in ("ell", "csr", "csr-seg", "hyb"):
                 raise ValueError(
                     f"semiring {self.semiring!r} plans support "
                     f"ell/csr/csr-seg/hyb, not {self.format_name!r}")
-            runners = {"ell": kl.spmv_ell_prepared,
-                       "csr": kl.spmv_csr_prepared,
-                       "csr-seg": kl.spmv_csr_seg_prepared,
-                       "hyb": kl.spmv_hyb_prepared}
-            return runners[self.format_name](self.prep, x,
-                                             interpret=interpret, semiring=sr)
-        runners = {
-            "dia": kl.spmv_dia_prepared,
-            "bell": kl.spmv_bell_prepared,
-            "ell": kl.spmv_ell_prepared,
-            "csr": kl.spmv_csr_prepared,
-            "csr-seg": kl.spmv_csr_seg_prepared,
-            "hyb": kl.spmv_hyb_prepared,
-        }
-        return runners[self.format_name](self.prep, x, interpret=interpret)
+            kwargs["semiring"] = sr
+        return RUNNERS[self.format_name], kwargs
+
+    def lower(self, x: jax.Array, interpret: Optional[bool] = None):
+        """`jax.stages.Lowered` of the program `execute` runs (permuted
+        order) for x's shape; `.compile().as_text()` shows whether the
+        kernel is a Mosaic custom call (`tpu_custom_call`)."""
+        if not self.use_pallas or self.format_name == "ell-sharded":
+            raise ValueError(f"{self.format_name!r} plans with use_pallas="
+                             f"{self.use_pallas} have no single runner")
+        runner, kwargs = self._runner(interpret)
+        return runner.lower(self.prep, x, **kwargs)
 
     def _source_container(self):
         container = self.container if self.container is not None else self.csr

@@ -316,6 +316,16 @@ def _run_chunk(chunk: List[SweepCell],
             for cell in chunk]
 
 
+def _cpu_worker() -> None:
+    """Spawn-worker initializer.  Sweep cells compute simulator numbers
+    only, so workers run JAX on the CPU, pinned before any backend
+    starts: an accelerator belongs to one process, and the parent may
+    hold it."""
+    import jax
+
+    jax.config.update("jax_platforms", "cpu")
+
+
 def _chunks(todo: List[SweepCell], workers: int) -> List[List[SweepCell]]:
     """Contiguous slices of the sorted order (so a chunk stays on one
     plan), at least ~4 chunks per worker for checkpoint granularity."""
@@ -366,8 +376,8 @@ def execute_cells(cells: Sequence[SweepCell],
                 _save(mgr, done)
     else:
         ctx = multiprocessing.get_context("spawn")
-        with ProcessPoolExecutor(max_workers=workers,
-                                 mp_context=ctx) as pool:
+        with ProcessPoolExecutor(max_workers=workers, mp_context=ctx,
+                                 initializer=_cpu_worker) as pool:
             futs = [pool.submit(_run_chunk, chunk, cfg)
                     for chunk in _chunks(todo, workers)]
             for fut in as_completed(futs):

@@ -19,6 +19,23 @@ def _x(n, dtype=np.float32, seed=0):
                        .astype(dtype))
 
 
+@pytest.mark.parametrize("backend,asked,want", [
+    ("cpu", None, True), ("cpu", True, True), ("cpu", False, False),
+    ("tpu", None, False), ("tpu", False, False), ("tpu", True, ValueError),
+])
+def test_backend_decides_interpret(monkeypatch, backend, asked, want):
+    """None resolves to the backend's mode; interpreting on a TPU raises."""
+    import jax
+    from repro.kernels import resolve_interpret
+
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    if want is ValueError:
+        with pytest.raises(ValueError):
+            resolve_interpret(asked)
+    else:
+        assert resolve_interpret(asked) is want
+
+
 # ---------------------------------------------------------------------------
 # DIA kernel
 # ---------------------------------------------------------------------------

@@ -24,7 +24,6 @@ _SCRIPT = textwrap.dedent("""
 
     assert len(jax.devices()) == 8
     from repro.launch.mesh import make_mesh
-    from repro.distributed.compat import shard_map
     mesh = make_mesh((2, 4), ("data", "model"))
 
     # ---- 1. sharded MoE parity on a real multi-device mesh ----
@@ -57,7 +56,7 @@ _SCRIPT = textwrap.dedent("""
     def f(x, w_shard):
         return ring_allgather_matmul(x, w_shard, "model")
 
-    y = jax.jit(shard_map(
+    y = jax.jit(jax.shard_map(
         f, mesh=mesh, in_specs=(P(None, None), P("model", None)),
         out_specs=P(None, None), check_vma=False))(xs, w_sharded)
     np.testing.assert_allclose(np.asarray(y), np.asarray(xs @ w),
@@ -75,7 +74,7 @@ _SCRIPT = textwrap.dedent("""
     def merged(q, k, v, valid):
         return lse_merge_attention(q, k, v, "model", valid)
 
-    out = jax.jit(shard_map(
+    out = jax.jit(jax.shard_map(
         merged, mesh=mesh,
         in_specs=(P(), P(None, "model", None, None),
                   P(None, "model", None, None), P(None, "model")),
@@ -113,7 +112,7 @@ _SCRIPT = textwrap.dedent("""
         out, _ = crosspod_allreduce_compressed(g, st2, "pod")
         return out
 
-    got = jax.jit(shard_map(
+    got = jax.jit(jax.shard_map(
         cp, mesh=mesh_p, in_specs=(P(), P()), out_specs=P(),
         check_vma=False))(g, st.residual)
     # psum of identical replicas / n == original (up to int8 quantization)

@@ -29,7 +29,6 @@ _SCRIPT = textwrap.dedent("""
                                             pipeline_apply, reference_apply)
 
     from repro.launch.mesh import make_mesh
-    from repro.distributed.compat import shard_map
     mesh = make_mesh((4,), ("stage",))
     cfg = PipelineConfig(n_stages=4, n_microbatches=8, axis_name="stage")
     stacked, stage_fn = make_pipelined_mlp(cfg, [16]*9, jax.random.PRNGKey(0))
@@ -39,7 +38,7 @@ _SCRIPT = textwrap.dedent("""
         # shard_map keeps a leading size-1 stage dim on the local shard
         return pipeline_apply(stage_fn, cfg, params[0], x)
 
-    outs = jax.jit(shard_map(
+    outs = jax.jit(jax.shard_map(
         run, mesh=mesh,
         in_specs=(P("stage"), P()), out_specs=P("stage"), check_vma=False,
     ))(stacked, x)

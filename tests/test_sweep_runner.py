@@ -182,6 +182,23 @@ def test_workers_bit_identical_to_serial(workers):
     assert sharded == serial
 
 
+def test_spawn_workers_run_jax_on_the_cpu(monkeypatch):
+    """Workers are pinned to the CPU before JAX starts a backend, so a
+    parent holding an accelerator never shares it with them: even with
+    an accelerator platform in the inherited environment, a worker's
+    JAX comes up on the CPU."""
+    import multiprocessing
+    from concurrent.futures import ProcessPoolExecutor
+
+    import jax
+
+    monkeypatch.setenv("JAX_PLATFORMS", "tpu")
+    with ProcessPoolExecutor(max_workers=1,
+                             mp_context=multiprocessing.get_context("spawn"),
+                             initializer=runner._cpu_worker) as pool:
+        assert pool.submit(jax.default_backend).result(timeout=120) == "cpu"
+
+
 def test_workers_resume_bit_identical(tmp_path):
     """Interrupt serially, finish sharded: still byte-identical."""
     straight = _payloads(execute_cells(GRID, CFG))
