@@ -1,18 +1,16 @@
 """Chip smoke: the SpMV plan path end to end, in one process, on a TPU.
 
-    python chip_smoke.py [--seed 0] [--log2n 22]     # one chip
-    python chip_smoke.py --chips 4                   # row-sharded path only
+    python chip_smoke.py [--seed 0]     # one chip
+    python chip_smoke.py --chips 4      # row-sharded path only
 
 One chip: generates the paper's two structures from `--seed` at
-2^log2n rows -- FD (9-point stencil, 9 nnz/row) and R-MAT (Graph500
+2^22 rows -- FD (9-point stencil, 9 nnz/row) and R-MAT (Graph500
 A/B/C quadrants, 8 nnz/row before dedup) -- and drives them through
 `plan.compile(a, reorder="none")`: one `execute(x)` and one
 `execute_many(X)` (k = 8) per matrix, each of the six formats forced
 once on a matrix where it is legal, and `graph.pagerank`, `graph.bfs`
-and `graph.sssp` on the R-MAT for a few iterations each.  BELL runs on
-FD at 2^min(log2n, 18): its scalar-prefetched block-column table is
-limited by the 1 MiB SMEM, and at 2^22 its 8x128 tiles alone would take
-12.9 GB of HBM.
+and `graph.sssp` on the R-MAT for a few iterations each.  BELL on FD is
+the largest program: its 8x128 tiles take 12.9 GB of the chip's 16 GB.
 
 `--chips 4`: only the row-sharded `shard_map` SpMV over a 4-device
 `row_mesh()` on FD, compared with the one-chip plan on the same x, with
@@ -23,7 +21,9 @@ from the CSR arrays, and every plan's runner must compile to a Mosaic
 kernel (`tpu_custom_call` in the compiled program).  No failure is
 caught.  Each phase prints one JSON line: the format the plan chose, the
 max error relative to the reference's largest magnitude, and host
-set-up, compile and run seconds (runs end in `block_until_ready`).  The
+set-up, compile and run seconds (runs end in `block_until_ready`;
+compile seconds are the runner's first compile, served from the
+persistent cache when an earlier run left it there).  The
 last line is `{"ok": true, "device": {...}}` as JAX reports the device;
 without a TPU the script exits 1 before doing any work.
 """
@@ -37,10 +37,10 @@ import time
 
 sys.path.insert(0, str(pathlib.Path(__file__).resolve().parent / "src"))
 
+LOG2N = 22          # rows of both matrices: 2^22
 TOL = 1e-4          # max |y - ref| / max |ref| for float32 against float64
 K = 8               # vectors in the execute_many phase
 ITERS = 3           # iterations of each graph analytic
-BELL_MAX_LOG2 = 18  # BELL's block-column table must fit 1 MiB of SMEM
 FORMATS = ("dia", "bell", "ell", "csr", "csr-seg", "hyb")
 
 
@@ -191,14 +191,23 @@ def spmv_phase(label: str, a, x, *, fmt=None, X=None, mosaic=True) -> dict:
 
 
 def graph_phase(label: str, a, *, mosaic=True) -> dict:
-    """Run one analytic on `a` for ITERS iterations through its driver
-    (own plan cache, freed afterwards) against the host reference."""
+    """Compile one analytic's plan into a cache of its own and compile
+    its runner, then run the analytic's driver on that cache (which finds
+    both) for ITERS iterations against the host reference."""
     import jax.numpy as jnp
     import numpy as np
     from repro import graph, plan
+    from repro.graph.drivers import analytic_operand, plan_options
 
     source = int(np.argmax(np.diff(np.asarray(a.indptr))))   # the hub
     cache = plan.PlanCache()
+    t = _now()
+    matrix, semiring, _ = analytic_operand(label, a)
+    p = cache.get_or_compile(matrix, **plan_options(semiring))
+    setup_s = _now() - t
+    del matrix
+    x = jnp.zeros((p.n_cols,), jnp.float32)
+    compile_s = _mosaic_compile(label, p.lower(x), mosaic)
     t = _now()
     if label == "pagerank":
         res = graph.pagerank(a, max_iters=ITERS, plan_cache=cache)
@@ -209,18 +218,14 @@ def graph_phase(label: str, a, *, mosaic=True) -> dict:
     else:
         res = graph.sssp(a, source, max_iters=ITERS, plan_cache=cache)
         ref = sssp_ref(a, source, res.n_iters)
-    total_s = _now() - t
-    p = res.plan
-    x = jnp.zeros((p.n_cols,), jnp.float32)
-    compile_s = _mosaic_compile(label, p.lower(x), mosaic)
+    driver_s = _now() - t
+    assert res.plan is p, f"{label}: the driver compiled another plan"
     err = rel_err(res.values, ref)
     _check(label, err)
-    stats = p.compile_stats
     rec = {"phase": f"rmat/{label}", "n": a.n_rows, "format": p.format_name,
            "semiring": p.semiring, "iters": res.n_iters,
-           "max_rel_err": err,
-           "setup_s": sum(v for k, v in stats.items() if k.endswith("_s")),
-           "compile_s": compile_s, "total_s": total_s}
+           "max_rel_err": err, "setup_s": setup_s, "compile_s": compile_s,
+           "driver_s": driver_s}
     del res, p, cache
     return _emit(rec)
 
@@ -253,24 +258,18 @@ def smoke(log2n: int, seed: int, mosaic: bool = True) -> list:
     fd = _generate("fd", fd_matrix, n, seed)
     x, X = _vectors(n, seed, K)
     recs.append(spmv_phase("fd/auto", fd, x, X=X, mosaic=mosaic))
-    for fmt in ("csr", "dia", "ell"):           # the FD-legal formats
-        if fmt != recs[-1]["format"]:
+    auto = recs[-1]["format"]
+    for fmt in ("csr", "dia", "ell", "bell"):   # the FD-legal formats
+        if fmt != auto:
             recs.append(spmv_phase(f"fd/{fmt}", fd, x, fmt=fmt,
                                    mosaic=mosaic))
     del fd
-    log2b = min(log2n, BELL_MAX_LOG2)
-    if log2b != log2n:
-        print(f"# bell runs on FD at 2^{log2b} rows, not 2^{log2n}: its "
-              "block-column table must fit SMEM", flush=True)
-    fd_b = _generate("fd-bell", fd_matrix, 1 << log2b, seed)
-    recs.append(spmv_phase("fd/bell", fd_b, _vectors(1 << log2b, seed),
-                           fmt="bell", mosaic=mosaic))
-    del fd_b
 
     rmat = _generate("rmat", rmat_matrix, n, seed)
     recs.append(spmv_phase("rmat/auto", rmat, x, X=X, mosaic=mosaic))
+    auto = recs[-1]["format"]
     for fmt in ("hyb", "csr-seg"):              # the nnz-balanced formats
-        if fmt != recs[-1]["format"]:
+        if fmt != auto:
             recs.append(spmv_phase(f"rmat/{fmt}", rmat, x, fmt=fmt,
                                    mosaic=mosaic))
     for analytic in ("pagerank", "bfs", "sssp"):
@@ -334,7 +333,6 @@ def smoke_sharded(log2n: int, seed: int, n_devices: int,
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--seed", type=int, default=0)
-    ap.add_argument("--log2n", type=int, default=22)
     ap.add_argument("--chips", type=int, choices=(1, 4), default=1,
                     help="4: run only the row-sharded path on four chips")
     args = ap.parse_args(argv)
@@ -354,13 +352,13 @@ def main(argv=None) -> int:
     from repro.compile_cache import enable_compile_cache
 
     cache_dir = enable_compile_cache()
-    print(f"# {dev.device_kind} x{len(devices)}, 2^{args.log2n} rows, "
+    print(f"# {dev.device_kind} x{len(devices)}, 2^{LOG2N} rows, "
           f"seed {args.seed}, compile cache {cache_dir}", flush=True)
     t = _now()
     if args.chips == 4:
-        smoke_sharded(args.log2n, args.seed, 4)
+        smoke_sharded(LOG2N, args.seed, 4)
     else:
-        smoke(args.log2n, args.seed)
+        smoke(LOG2N, args.seed)
     print(f"# total {_now() - t:.1f} s", flush=True)
     print(json.dumps({"ok": True, "device": {
         "platform": dev.platform, "kind": dev.device_kind,

@@ -22,6 +22,7 @@ from __future__ import annotations
 from typing import Optional
 
 import jax
+import jax.numpy as jnp
 
 
 def resolve_interpret(interpret: Optional[bool] = None) -> bool:
@@ -41,10 +42,6 @@ def resolve_interpret(interpret: Optional[bool] = None) -> bool:
 
 def gather_x(x: jax.Array, idx: jax.Array) -> jax.Array:
     """x[idx] as one XLA gather from HBM, for the kernels Mosaic cannot
-    gather in (ELL, CSR, CSR-seg).  Layouts build every index in range,
-    so the gather skips jnp's negative-index wrap, an extra elementwise
-    pass over `idx`."""
-    dnums = jax.lax.GatherDimensionNumbers(
-        offset_dims=(), collapsed_slice_dims=(0,), start_index_map=(0,))
-    return jax.lax.gather(x, idx[..., None], dnums, slice_sizes=(1,),
-                          mode=jax.lax.GatherScatterMode.PROMISE_IN_BOUNDS)
+    gather in (ELL, CSR, CSR-seg).  jnp.take's default bounds handling
+    stays: an index out of range reads NaN, not stray memory."""
+    return jnp.take(x, idx)

@@ -146,14 +146,14 @@ class SpmvPlan:
             kwargs["semiring"] = sr
         return RUNNERS[self.format_name], kwargs
 
-    def lower(self, x: jax.Array, interpret: Optional[bool] = None):
+    def lower(self, x: jax.Array):
         """`jax.stages.Lowered` of the program `execute` runs (permuted
         order) for x's shape; `.compile().as_text()` shows whether the
         kernel is a Mosaic custom call (`tpu_custom_call`)."""
         if not self.use_pallas or self.format_name == "ell-sharded":
             raise ValueError(f"{self.format_name!r} plans with use_pallas="
                              f"{self.use_pallas} have no single runner")
-        runner, kwargs = self._runner(interpret)
+        runner, kwargs = self._runner(None)
         return runner.lower(self.prep, x, **kwargs)
 
     def _source_container(self):

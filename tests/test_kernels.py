@@ -91,6 +91,23 @@ def test_bell_kernel_vs_oracle(n, seed):
     np.testing.assert_allclose(np.asarray(got), want, rtol=1e-4, atol=1e-4)
 
 
+def test_bell_table_past_smem_runs_in_calls(monkeypatch):
+    """A block-column table larger than SMEM_TABLE_WORDS is cut into
+    calls of whole block rows; the last call is ragged."""
+    from repro.kernels import spmv_bell
+
+    csr = rmat_matrix(512, seed=4)
+    bell = BELL.from_csr(csr)
+    nbr, bpr = bell.block_cols.shape
+    monkeypatch.setattr(spmv_bell, "SMEM_TABLE_WORDS", 5 * bpr)
+    assert nbr % 5                                 # 5 block rows per call
+    x = _x(512, seed=4)
+    got = spmv_bell.spmv_bell_pallas.__wrapped__(
+        bell.data, bell.block_cols, x, interpret=True)
+    want = np.asarray(csr.to_dense()) @ np.asarray(x)
+    np.testing.assert_allclose(np.asarray(got), want, rtol=1e-4, atol=1e-4)
+
+
 def test_bell_bf16_inputs_fp32_accum():
     csr = rmat_matrix(256, seed=2)
     bell = BELL.from_csr(csr)

@@ -58,9 +58,9 @@ def no_compile_cache():
 
 def _layouts(sharding):
     """Prepared layouts at the 2^22-row shapes the chip smoke builds:
-    FD (9 nnz/row) for DIA, ELL and CSR, FD at 2^18 for BELL (its
-    block-column table must fit SMEM), R-MAT (~33M nnz, hub rows) for
-    CSR-seg and HYB."""
+    FD (9 nnz/row) for DIA, BELL, ELL and CSR (BELL's 12.6 MB
+    block-column table runs in SMEM-sized calls), R-MAT (~33M nnz, hub
+    rows) for CSR-seg and HYB."""
     def s(shape, dtype=jnp.float32):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
 
@@ -76,14 +76,14 @@ def _layouts(sharding):
             nonempty=s((N,), jnp.bool_), n_rows=N, n_cols=N, rwin=rwin,
             seg_len=512, x_pad=N)
 
-    nbr = (1 << 18) // 8
+    nbr = N // 8
     return {
         "dia": (kl.spmv_dia_prepared, kl.PreparedDIA(
             band=s((9, N)), offsets=s((9,), i32), n_rows=N, n_cols=N,
             bn=512), N),
         "bell": (kl.spmv_bell_prepared, kl.PreparedBELL(
             data=s((nbr, 6, 8, 128)), block_cols=s((nbr, 6), i32),
-            n_rows=nbr * 8, n_cols=nbr * 8, x_pad=nbr * 8), nbr * 8),
+            n_rows=N, n_cols=N, x_pad=N), N),
         "ell": (kl.spmv_ell_prepared, ell, N),
         "csr": (kl.spmv_csr_prepared, kl.PaddedCSR(
             vals=s((1, b, 1152)), cols=s((1, b, 1152), i32),
