@@ -16,6 +16,7 @@ control reordering and serialize the planned shards.
 """
 from __future__ import annotations
 
+import functools
 from typing import Optional
 
 import jax
@@ -30,6 +31,7 @@ from repro.kernels import spmv_ell as _ell
 from repro.kernels._layout import (ShardedELL, round_up,           # noqa: F401
                                    prepare_ell_shards)  # re-exported for
                                                         # pre-plan callers
+from repro.spans import KERNEL, STITCH, X_PREP
 
 _AXIS = "shards"
 
@@ -103,10 +105,12 @@ def row_sharded_program(prep: ShardedELL, mesh: Mesh,
     def one_shard(data, idx, xv):
         # data/idx arrive as this device's (1, rows_pad, w) slab
         b_dim = rows_pad // bm
-        y = _ell.spmv_ell_pallas(data.reshape(b_dim, bm, w),
-                                 idx.reshape(b_dim, bm, w),
-                                 xv, interpret=interpret)
-        return y.reshape(1, rows_pad)
+        with jax.named_scope(KERNEL):
+            data = data.reshape(b_dim, bm, w)
+            idx = idx.reshape(b_dim, bm, w)
+        y = _ell.spmv_ell_pallas(data, idx, xv, interpret=interpret)
+        with jax.named_scope(KERNEL):
+            return y.reshape(1, rows_pad)
 
     return jax.jit(jax.shard_map(
         one_shard, mesh=mesh,
@@ -117,15 +121,29 @@ def row_sharded_program(prep: ShardedELL, mesh: Mesh,
         check_vma=False))
 
 
+@functools.partial(jax.jit, static_argnames=("size",))
+def _pad_to(x: jax.Array, size: int) -> jax.Array:
+    with jax.named_scope(X_PREP):
+        return jnp.pad(x, (0, size - x.shape[0]))
+
+
 def pad_x(prep: ShardedELL, x: jax.Array) -> jax.Array:
     """x padded to the lane multiple every shard's column indices fit."""
-    return jnp.pad(x, (0, round_up(prep.n_cols, 128) - prep.n_cols))
+    return _pad_to(x, round_up(prep.n_cols, 128))
+
+
+@functools.partial(jax.jit, static_argnames=("rows", "n_rows"))
+def _stitch(y_slabs: jax.Array, rows: tuple, n_rows: int) -> jax.Array:
+    """y from the (parts, rows_pad) slabs: part p's first rows[p] rows,
+    in order."""
+    with jax.named_scope(STITCH):
+        parts = [y_slabs[p, :r] for p, r in enumerate(rows)]
+        return jnp.concatenate(parts)[:n_rows]
 
 
 def spmv_row_sharded_prepared(prep: ShardedELL, x: jax.Array, mesh: Mesh,
                               interpret: Optional[bool] = None) -> jax.Array:
     y_slabs = row_sharded_program(prep, mesh, interpret)(
         prep.data, prep.idx, pad_x(prep, x))              # (parts, rows_pad)
-    parts = [y_slabs[p, : int(prep.starts[p + 1] - prep.starts[p])]
-             for p in range(y_slabs.shape[0])]
-    return jnp.concatenate(parts)[: prep.n_rows]
+    rows = tuple(int(r) for r in np.diff(prep.starts))
+    return _stitch(y_slabs, rows, prep.n_rows)

@@ -30,6 +30,13 @@ one SpMV's worth of memory traffic, nothing else -- which is what lets
 `telemetry.sweep.graph_sweep` replay a whole analytic from the plan's
 memoized address trace.
 
+Each blocking driver call runs in a `graph.query` span (with the
+analytic and a per-process query number), its host steps in child spans
+(`repro.spans`): `graph.operand`, the plan cache's `plan_cache.get`, and
+per iteration `graph.iteration` around `spmv.execute`, `graph.host_sync`
+and `graph.advance`.  `recent_queries()` keeps the seconds of each,
+summed over the query, for the last few queries.
+
 Graph convention: the input is a square CSR adjacency with A[i, j] != 0
 meaning an edge i -> j (weight = stored value).  SpMV computes
 y[i] = ⊕_j A[i,j] ⊗ x[j] -- a *pull* along rows -- so push-style
@@ -39,13 +46,17 @@ plan-compile time.  Undirected graphs should be stored symmetrically
 """
 from __future__ import annotations
 
+import collections
+import contextlib
 import dataclasses
+import itertools
 from typing import Callable, Dict, List, Optional, Sequence, Tuple, Union
 
 import jax.numpy as jnp
 import numpy as np
 
 from repro.core.formats import CSR
+from repro.spans import collect, span
 
 from .semiring import MIN_PLUS, OR_AND, PLUS_TIMES, Semiring
 
@@ -404,12 +415,14 @@ ANALYTICS: Dict[str, AnalyticDef] = {
 def analytic_operand(analytic: str, adj: CSR) -> Tuple[CSR, str, Dict]:
     """(operand matrix, semiring name, aux) for one analytic -- the
     host-side derivation `serve_graph` admission performs once per
-    (graph, analytic) before consulting the plan cache."""
+    (graph, analytic) before consulting the plan cache; a
+    `graph.operand` span."""
     d = ANALYTICS.get(analytic)
     if d is None:
         raise ValueError(f"unknown analytic {analytic!r}; "
                          f"have {sorted(ANALYTICS)}")
-    matrix, aux = d.operand(adj)
+    with span("graph.operand"):
+        matrix, aux = d.operand(adj)
     return matrix, d.semiring.name, aux
 
 
@@ -455,6 +468,27 @@ def warm_start_params(analytic: str, values, delta=None) -> Optional[Dict]:
     return {kw: np.asarray(values, dtype=np.float32)}
 
 
+_QUERY_NUMBERS = itertools.count(1)
+_RECENT: collections.deque = collections.deque(maxlen=64)
+
+
+def recent_queries() -> List[Tuple[str, Dict[str, float]]]:
+    """(analytic, seconds by span name) of this process's last 64
+    blocking driver calls, oldest first."""
+    return list(_RECENT)
+
+
+@contextlib.contextmanager
+def _query(analytic: str):
+    """One driver call's `graph.query` span; the seconds of the spans
+    inside it go to `recent_queries`."""
+    with collect() as host_s:
+        with span("graph.query", analytic=analytic,
+                  query=next(_QUERY_NUMBERS)):
+            yield
+    _RECENT.append((analytic, host_s))
+
+
 def _drive(stepper, plan, max_iters: int, multi: bool) -> GraphResult:
     """The blocking driver loop: pull `frontier()`, run the plan, feed
     `advance()` -- single-source stays on the 1-D Pallas `execute` path
@@ -464,12 +498,16 @@ def _drive(stepper, plan, max_iters: int, multi: bool) -> GraphResult:
     it = 0
     while it < max_iters and not stepper.done:
         it += 1
-        F = stepper.frontier()
-        if multi:
-            y = np.asarray(plan.execute_many(jnp.asarray(F)))
-        else:
-            y = np.asarray(plan.execute(jnp.asarray(F)[0]))[None]
-        history.append(stepper.advance(y))
+        with span("graph.iteration", it=it):
+            F = stepper.frontier()
+            if multi:
+                y = plan.execute_many(jnp.asarray(F))
+            else:
+                y = plan.execute(jnp.asarray(F)[0])
+            with span("graph.host_sync"):
+                y = np.asarray(y)
+            with span("graph.advance"):
+                history.append(stepper.advance(y if multi else y[None]))
     vals = stepper.values()
     return GraphResult(values=vals if multi else vals[0], n_iters=it,
                        converged=bool(stepper.done), history=history,
@@ -494,12 +532,13 @@ def pagerank(adj: CSR, damping: float = 0.85, tol: float = 1e-8,
     grids) the uniform vector is already the fixpoint, so a perturbed
     start is what makes the iteration count meaningful there.
     """
-    matrix, _, aux = analytic_operand("pagerank", adj)
-    p = _graph_plan(matrix, PLUS_TIMES, reorder=reorder, format=format,
-                    plan_cache=plan_cache, use_pallas=use_pallas,
-                    interpret=interpret)
-    st = PageRankStepper(p, aux, damping=damping, tol=tol, r0=r0)
-    return _drive(st, p, max_iters, multi=False)
+    with _query("pagerank"):
+        matrix, _, aux = analytic_operand("pagerank", adj)
+        p = _graph_plan(matrix, PLUS_TIMES, reorder=reorder, format=format,
+                        plan_cache=plan_cache, use_pallas=use_pallas,
+                        interpret=interpret)
+        st = PageRankStepper(p, aux, damping=damping, tol=tol, r0=r0)
+        return _drive(st, p, max_iters, multi=False)
 
 
 def bfs(adj: CSR, source: Union[int, Sequence[int]],
@@ -520,13 +559,15 @@ def bfs(adj: CSR, source: Union[int, Sequence[int]],
     """
     n = _require_square(adj, "bfs")
     multi = np.ndim(source) > 0
-    matrix, _, aux = analytic_operand("bfs", adj)
-    p = _graph_plan(matrix, OR_AND, reorder=reorder, format=format,
-                    plan_cache=plan_cache, use_pallas=use_pallas,
-                    interpret=interpret)
-    st = BfsStepper(p, aux, sources=np.atleast_1d(
-        np.asarray(source, dtype=np.int64)))
-    return _drive(st, p, n if max_iters is None else max_iters, multi=multi)
+    with _query("bfs"):
+        matrix, _, aux = analytic_operand("bfs", adj)
+        p = _graph_plan(matrix, OR_AND, reorder=reorder, format=format,
+                        plan_cache=plan_cache, use_pallas=use_pallas,
+                        interpret=interpret)
+        st = BfsStepper(p, aux, sources=np.atleast_1d(
+            np.asarray(source, dtype=np.int64)))
+        return _drive(st, p, n if max_iters is None else max_iters,
+                      multi=multi)
 
 
 def sssp(adj: CSR, source: int, max_iters: Optional[int] = None, *,
@@ -543,12 +584,14 @@ def sssp(adj: CSR, source: int, max_iters: Optional[int] = None, *,
     distances (valid after insert-only graph deltas; see `SsspStepper`).
     """
     n = _require_square(adj, "sssp")
-    matrix, _, aux = analytic_operand("sssp", adj)
-    p = _graph_plan(matrix, MIN_PLUS, reorder=reorder, format=format,
-                    plan_cache=plan_cache, use_pallas=use_pallas,
-                    interpret=interpret)
-    st = SsspStepper(p, aux, sources=[source], d0=d0)
-    return _drive(st, p, n if max_iters is None else max_iters, multi=False)
+    with _query("sssp"):
+        matrix, _, aux = analytic_operand("sssp", adj)
+        p = _graph_plan(matrix, MIN_PLUS, reorder=reorder, format=format,
+                        plan_cache=plan_cache, use_pallas=use_pallas,
+                        interpret=interpret)
+        st = SsspStepper(p, aux, sources=[source], d0=d0)
+        return _drive(st, p, n if max_iters is None else max_iters,
+                      multi=False)
 
 
 def connected_components(adj: CSR, max_iters: Optional[int] = None, *,
@@ -567,19 +610,21 @@ def connected_components(adj: CSR, max_iters: Optional[int] = None, *,
     representable: graphs beyond 2^24 rows are refused rather than
     silently merging components whose seed ids collide in f32."""
     n = _require_square(adj, "connected_components")
-    matrix, _, aux = analytic_operand("connected_components", adj)
-    p = _graph_plan(matrix, MIN_PLUS, reorder=reorder, format=format,
-                    plan_cache=plan_cache, use_pallas=use_pallas,
-                    interpret=interpret)
-    st = CcStepper(p, aux, l0=l0)
-    return _drive(st, p, n if max_iters is None else max_iters, multi=False)
+    with _query("connected_components"):
+        matrix, _, aux = analytic_operand("connected_components", adj)
+        p = _graph_plan(matrix, MIN_PLUS, reorder=reorder, format=format,
+                        plan_cache=plan_cache, use_pallas=use_pallas,
+                        interpret=interpret)
+        st = CcStepper(p, aux, l0=l0)
+        return _drive(st, p, n if max_iters is None else max_iters,
+                      multi=False)
 
 
 DRIVERS = {"pagerank": pagerank, "bfs": bfs, "sssp": sssp,
            "connected_components": connected_components}
 
 __all__ = ["GraphResult", "transpose_csr", "pagerank", "bfs", "sssp",
-           "connected_components", "DRIVERS",
+           "connected_components", "DRIVERS", "recent_queries",
            "AnalyticDef", "ANALYTICS", "analytic_operand", "make_stepper",
            "check_sources", "plan_options",
            "warm_start_params", "WARM_START_PARAM",

@@ -24,6 +24,8 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
+from repro.spans import X_GATHER
+
 
 def resolve_interpret(interpret: Optional[bool] = None) -> bool:
     """Whether Pallas kernels run in interpret mode, decided by the backend.
@@ -42,6 +44,15 @@ def resolve_interpret(interpret: Optional[bool] = None) -> bool:
 
 def gather_x(x: jax.Array, idx: jax.Array) -> jax.Array:
     """x[idx] as one XLA gather from HBM, for the kernels Mosaic cannot
-    gather in (ELL, CSR, CSR-seg).  jnp.take's default bounds handling
-    stays: an index out of range reads NaN, not stray memory."""
-    return jnp.take(x, idx)
+    gather in (ELL, CSR, CSR-seg).  A 2-D x is a stack of S column
+    stripes, and idx[s] indexes stripe s.  jnp.take's default bounds
+    handling stays: an index out of range reads NaN, not stray memory.
+    The gather and the index arithmetic feeding it share the
+    `spmv.x_gather` stage scope."""
+    with jax.named_scope(X_GATHER):
+        if x.ndim == 2:
+            s_dim, stripe_w = x.shape
+            base = jnp.arange(s_dim, dtype=idx.dtype) * stripe_w
+            idx = idx + base.reshape((s_dim,) + (1,) * (idx.ndim - 1))
+            x = x.reshape(-1)
+        return jnp.take(x, idx)

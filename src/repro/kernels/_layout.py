@@ -11,6 +11,11 @@ This split is what `repro.plan` builds on: `prepare_*` runs at plan-compile
 time, `spmv_*_prepared` is the amortized hot path.  The per-call wrappers in
 `kernels.ops` are now just `prepare_*` + `spmv_*_prepared` composed.
 
+Every op of a runner lies in one stage scope (`repro.spans.SCOPES`): the
+x pad and reshape in `spmv.x_prep`, the gather in `spmv.x_gather`, the
+Pallas call in `spmv.kernel`, and what turns its output into y in
+`spmv.merge`.
+
 The `Prepared*` containers are pytrees (arrays are leaves, sizes and
 knobs are static), so each runner is one jitted program taking the layout
 as an argument: the x pad, the XLA gather, the kernel and the merge fuse
@@ -26,6 +31,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core.formats import BELL, CSR, DIA, ELL, HYB
+from repro.spans import MERGE, X_PREP
 
 from . import spmv_bell as _bell
 from . import spmv_csr as _csr
@@ -91,10 +97,12 @@ def prepare_dia(dia: DIA, bn: int = 512) -> PreparedDIA:
 @_runner_plus_times
 def spmv_dia_prepared(prep: PreparedDIA, x: jax.Array,
                       interpret: bool | None = None) -> jax.Array:
-    xp = jnp.pad(x, (0, prep.band.shape[1] - x.shape[0]))
+    with jax.named_scope(X_PREP):
+        xp = jnp.pad(x, (0, prep.band.shape[1] - x.shape[0]))
     y = _dia.spmv_dia_pallas(prep.band, prep.offsets, xp, bn=prep.bn,
                              interpret=interpret)
-    return y[: prep.n_rows]
+    with jax.named_scope(MERGE):
+        return y[: prep.n_rows]
 
 
 # ---------------------------------------------------------------------------
@@ -121,10 +129,12 @@ def prepare_bell(bell: BELL) -> PreparedBELL:
 @_runner_plus_times
 def spmv_bell_prepared(prep: PreparedBELL, x: jax.Array,
                        interpret: bool | None = None) -> jax.Array:
-    xp = jnp.pad(x, (0, prep.x_pad - prep.n_cols))
+    with jax.named_scope(X_PREP):
+        xp = jnp.pad(x, (0, prep.x_pad - prep.n_cols))
     y = _bell.spmv_bell_pallas(prep.data, prep.block_cols, xp,
                                interpret=interpret)
-    return y[: prep.n_rows]
+    with jax.named_scope(MERGE):
+        return y[: prep.n_rows]
 
 
 # ---------------------------------------------------------------------------
@@ -168,10 +178,12 @@ def prepare_ell(ell: ELL, bm: int = 128, pad_mult: int = 128,
 def spmv_ell_prepared(prep: PreparedELL, x: jax.Array,
                       interpret: bool | None = None,
                       semiring=None) -> jax.Array:
-    xp = jnp.pad(x, (0, prep.x_pad - prep.n_cols))
+    with jax.named_scope(X_PREP):
+        xp = jnp.pad(x, (0, prep.x_pad - prep.n_cols))
     y = _ell.spmv_ell_pallas(prep.data, prep.idx, xp, interpret=interpret,
                              semiring=semiring)
-    return y.reshape(-1)[: prep.n_rows]
+    with jax.named_scope(MERGE):
+        return y.reshape(-1)[: prep.n_rows]
 
 
 # ---------------------------------------------------------------------------
@@ -283,16 +295,18 @@ def spmv_csr_prepared(prep: PaddedCSR, x: jax.Array,
                       interpret: bool | None = None,
                       semiring=None) -> jax.Array:
     s_dim = prep.vals.shape[0]
-    xp = jnp.pad(x, (0, s_dim * prep.stripe_w - prep.n_cols))
-    x_stripes = xp.reshape(s_dim, prep.stripe_w)
+    with jax.named_scope(X_PREP):
+        xp = jnp.pad(x, (0, s_dim * prep.stripe_w - prep.n_cols))
+        x_stripes = xp.reshape(s_dim, prep.stripe_w)
     partials = _csr.spmv_csr_pallas(prep.vals, prep.cols, prep.rowin,
                                     x_stripes, interpret=interpret,
                                     semiring=semiring)
-    if semiring is None or semiring.name == "plus_times":
-        y = partials.sum(axis=0).reshape(-1)  # reduce over stripes
-    else:
-        y = semiring.reduce(partials, axis=0).reshape(-1)
-    return y[: prep.n_rows]
+    with jax.named_scope(MERGE):
+        if semiring is None or semiring.name == "plus_times":
+            y = partials.sum(axis=0).reshape(-1)  # reduce over stripes
+        else:
+            y = semiring.reduce(partials, axis=0).reshape(-1)
+        return y[: prep.n_rows]
 
 
 # ---------------------------------------------------------------------------
@@ -375,22 +389,24 @@ def prepare_csr_seg(csr: CSR, seg_len: int = 512, pad_mult: int = 128,
 def spmv_csr_seg_prepared(prep: PreparedSegCSR, x: jax.Array,
                           interpret: bool | None = None,
                           semiring=None) -> jax.Array:
-    xp = jnp.pad(x, (0, prep.x_pad - prep.n_cols))
+    with jax.named_scope(X_PREP):
+        xp = jnp.pad(x, (0, prep.x_pad - prep.n_cols))
     partials = _seg.spmv_csr_seg_pallas(prep.vals, prep.cols, prep.rid, xp,
                                         rwin=prep.rwin, interpret=interpret,
                                         semiring=semiring)
-    flat, ids = partials.reshape(-1), prep.row_ids.reshape(-1)
-    if semiring is None or semiring.name == "plus_times":
-        # carry-out merge: rows straddling a segment boundary have one
-        # rank in each segment; the segment sum stitches them together.
-        return jax.ops.segment_sum(flat, ids,
-                                   num_segments=prep.n_rows + 1)[: prep.n_rows]
-    y = semiring.segment(flat, ids,
-                         num_segments=prep.n_rows + 1)[: prep.n_rows]
-    # jax's segment_min/max fill empty segments with +/-inf, which is only
-    # the ⊕-identity for min_plus -- restore it for the rest.
-    return jnp.where(prep.nonempty, y, jnp.asarray(semiring.identity,
-                                                   y.dtype))
+    with jax.named_scope(MERGE):
+        flat, ids = partials.reshape(-1), prep.row_ids.reshape(-1)
+        if semiring is None or semiring.name == "plus_times":
+            # carry-out merge: rows straddling a segment boundary have one
+            # rank in each segment; the segment sum stitches them together.
+            return jax.ops.segment_sum(
+                flat, ids, num_segments=prep.n_rows + 1)[: prep.n_rows]
+        y = semiring.segment(flat, ids,
+                             num_segments=prep.n_rows + 1)[: prep.n_rows]
+        # jax's segment_min/max fill empty segments with +/-inf, which is
+        # only the ⊕-identity for min_plus -- restore it for the rest.
+        return jnp.where(prep.nonempty, y, jnp.asarray(semiring.identity,
+                                                       y.dtype))
 
 
 # ---------------------------------------------------------------------------
@@ -439,9 +455,10 @@ def spmv_hyb_prepared(prep: PreparedHYB, x: jax.Array,
                                 semiring=semiring)
     y_heavy = spmv_csr_seg_prepared(prep.heavy, x, interpret=interpret,
                                     semiring=semiring)
-    if semiring is None or semiring.name == "plus_times":
-        return y_light + y_heavy
-    return semiring.add(y_light, y_heavy)
+    with jax.named_scope(MERGE):
+        if semiring is None or semiring.name == "plus_times":
+            return y_light + y_heavy
+        return semiring.add(y_light, y_heavy)
 
 
 __all__ = [

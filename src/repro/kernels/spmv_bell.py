@@ -34,6 +34,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.spans import KERNEL, X_PREP
+
 from . import resolve_interpret
 
 
@@ -66,6 +68,7 @@ def _call(data, x_tiles, table, base, rows, interpret):
     last = nbr - 1
     return pl.pallas_call(
         _kernel,
+        name="spmv_bell_pallas",
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
             grid=(rows, bpr),
@@ -98,14 +101,16 @@ def spmv_bell_pallas(data: jax.Array, block_cols: jax.Array, x: jax.Array,
     """
     nbr, bpr, bm, bn = data.shape
     assert x.shape[0] % bn == 0
-    x_tiles = x.reshape(-1, 1, bn)
+    with jax.named_scope(X_PREP):
+        x_tiles = x.reshape(-1, 1, bn)
     rows = max(1, min(nbr, SMEM_TABLE_WORDS // bpr))
     n_calls = -(-nbr // rows)
-    table = jnp.pad(block_cols.reshape(-1).astype(jnp.int32),
-                    (0, (n_calls * rows - nbr) * bpr))
-    bases = jnp.arange(n_calls, dtype=jnp.int32)[:, None] * rows
     interpret = resolve_interpret(interpret)
-    out = jax.lax.map(
-        lambda tb: _call(data, x_tiles, tb[0], tb[1], rows, interpret),
-        (table.reshape(n_calls, rows * bpr), bases))
-    return out.reshape(-1)[: nbr * bm]
+    with jax.named_scope(KERNEL):
+        table = jnp.pad(block_cols.reshape(-1).astype(jnp.int32),
+                        (0, (n_calls * rows - nbr) * bpr))
+        bases = jnp.arange(n_calls, dtype=jnp.int32)[:, None] * rows
+        out = jax.lax.map(
+            lambda tb: _call(data, x_tiles, tb[0], tb[1], rows, interpret),
+            (table.reshape(n_calls, rows * bpr), bases))
+        return out.reshape(-1)[: nbr * bm]

@@ -31,6 +31,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.spans import KERNEL
+
 from . import gather_x, resolve_interpret
 
 
@@ -76,24 +78,26 @@ def spmv_csr_pallas(vals: jax.Array, cols: jax.Array, rowin: jax.Array,
         semiring = None
     s_dim, b_dim, w = vals.shape
     bm = 128  # rows per block (fixed by _layout.prepare_csr)
-    stripe_w = x_stripes.shape[1]
     # XLA gather: each stripe's rebased columns index its own x stripe
-    base = (jnp.arange(s_dim, dtype=jnp.int32) * stripe_w)[:, None, None]
-    xg = gather_x(x_stripes.reshape(-1), cols + base)
+    xg = gather_x(x_stripes, cols)
 
     def cells(a):                       # (S, B, W) -> (S, B, 1, W) blocks
         return a.reshape(s_dim, b_dim, 1, w)
 
     block = pl.BlockSpec((1, 1, 1, w), lambda s, b: (s, b, 0, 0))
-    partials = pl.pallas_call(
-        functools.partial(_kernel, bm=bm, semiring=semiring),
-        grid=(s_dim, b_dim),
-        in_specs=[block, block, block],
-        out_specs=pl.BlockSpec((1, 1, 1, bm), lambda s, b: (s, b, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((s_dim, b_dim, 1, bm), vals.dtype),
-        interpret=resolve_interpret(interpret),
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary"),
-        ),
-    )(cells(vals), cells(xg), cells(rowin))
-    return partials.reshape(s_dim, b_dim, bm)
+    with jax.named_scope(KERNEL):
+        partials = pl.pallas_call(
+            functools.partial(_kernel, bm=bm, semiring=semiring),
+            name="spmv_csr_pallas",
+            grid=(s_dim, b_dim),
+            in_specs=[block, block, block],
+            out_specs=pl.BlockSpec((1, 1, 1, bm),
+                                   lambda s, b: (s, b, 0, 0)),
+            out_shape=jax.ShapeDtypeStruct((s_dim, b_dim, 1, bm),
+                                           vals.dtype),
+            interpret=resolve_interpret(interpret),
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("parallel", "arbitrary"),
+            ),
+        )(cells(vals), cells(xg), cells(rowin))
+        return partials.reshape(s_dim, b_dim, bm)

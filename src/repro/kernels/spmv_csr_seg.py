@@ -37,6 +37,8 @@ import jax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.spans import KERNEL
+
 from . import gather_x, resolve_interpret
 from .spmv_csr import segment_reduce
 
@@ -73,15 +75,17 @@ def spmv_csr_seg_pallas(vals: jax.Array, cols: jax.Array, rid: jax.Array,
         return a.reshape(s_dim, 1, seg_len)
 
     block = pl.BlockSpec((1, 1, seg_len), lambda s: (s, 0, 0))
-    partials = pl.pallas_call(
-        functools.partial(_kernel, rwin=rwin, semiring=semiring),
-        grid=(s_dim,),
-        in_specs=[block, block, block],
-        out_specs=pl.BlockSpec((1, 1, rwin), lambda s: (s, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((s_dim, 1, rwin), vals.dtype),
-        interpret=resolve_interpret(interpret),
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel",),
-        ),
-    )(segs(vals), segs(xg), segs(rid))
-    return partials.reshape(s_dim, rwin)
+    with jax.named_scope(KERNEL):
+        partials = pl.pallas_call(
+            functools.partial(_kernel, rwin=rwin, semiring=semiring),
+            name="spmv_csr_seg_pallas",
+            grid=(s_dim,),
+            in_specs=[block, block, block],
+            out_specs=pl.BlockSpec((1, 1, rwin), lambda s: (s, 0, 0)),
+            out_shape=jax.ShapeDtypeStruct((s_dim, 1, rwin), vals.dtype),
+            interpret=resolve_interpret(interpret),
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("parallel",),
+            ),
+        )(segs(vals), segs(xg), segs(rid))
+        return partials.reshape(s_dim, rwin)

@@ -27,6 +27,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.spans import KERNEL, X_PREP
+
 from . import resolve_interpret
 
 
@@ -60,7 +62,8 @@ def spmv_dia_pallas(band: jax.Array, offsets: jax.Array, x: jax.Array,
     # halo covers the largest |offset|, rounded up to a block multiple
     halo_blocks = 1 + (n - 1) // bn          # offsets bounded by |off| < n
     halo = halo_blocks * bn
-    xp = jnp.pad(x, (halo, halo)).reshape(1, -1)
+    with jax.named_scope(X_PREP):
+        xp = jnp.pad(x, (halo, halo)).reshape(1, -1)
 
     grid = (n // bn, d)
 
@@ -70,22 +73,24 @@ def spmv_dia_pallas(band: jax.Array, offsets: jax.Array, x: jax.Array,
     def xhi_map(i, j, offs):
         return (0, (i * bn + offs[j] + halo) // bn + 1)
 
-    out = pl.pallas_call(
-        functools.partial(_kernel, halo=halo, bn=bn),
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1,
-            grid=grid,
-            in_specs=[
-                pl.BlockSpec((1, 1, bn), lambda i, j, offs: (j, 0, i)),
-                pl.BlockSpec((1, bn), xlo_map),                     # x low
-                pl.BlockSpec((1, bn), xhi_map),                     # x high
-            ],
-            out_specs=pl.BlockSpec((1, bn), lambda i, j, offs: (0, i)),
-        ),
-        out_shape=jax.ShapeDtypeStruct((1, n), band.dtype),
-        interpret=resolve_interpret(interpret),
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary"),
-        ),
-    )(offsets.astype(jnp.int32), band.reshape(d, 1, n), xp, xp)
-    return out[0]
+    with jax.named_scope(KERNEL):
+        out = pl.pallas_call(
+            functools.partial(_kernel, halo=halo, bn=bn),
+            name="spmv_dia_pallas",
+            grid_spec=pltpu.PrefetchScalarGridSpec(
+                num_scalar_prefetch=1,
+                grid=grid,
+                in_specs=[
+                    pl.BlockSpec((1, 1, bn), lambda i, j, offs: (j, 0, i)),
+                    pl.BlockSpec((1, bn), xlo_map),                 # x low
+                    pl.BlockSpec((1, bn), xhi_map),                 # x high
+                ],
+                out_specs=pl.BlockSpec((1, bn), lambda i, j, offs: (0, i)),
+            ),
+            out_shape=jax.ShapeDtypeStruct((1, n), band.dtype),
+            interpret=resolve_interpret(interpret),
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("parallel", "arbitrary"),
+            ),
+        )(offsets.astype(jnp.int32), band.reshape(d, 1, n), xp, xp)
+        return out[0]

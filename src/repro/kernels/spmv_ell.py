@@ -29,6 +29,8 @@ import jax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.spans import KERNEL
+
 from . import gather_x, resolve_interpret
 
 
@@ -60,15 +62,17 @@ def spmv_ell_pallas(data: jax.Array, idx: jax.Array, x: jax.Array,
     b_dim, bm, w = data.shape
     xg = gather_x(x, idx)
     block = pl.BlockSpec((1, bm, w), lambda b: (b, 0, 0))
-    y = pl.pallas_call(
-        functools.partial(_kernel, semiring=semiring),
-        grid=(b_dim,),
-        in_specs=[block, block],
-        out_specs=pl.BlockSpec((1, 1, bm), lambda b: (b, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((b_dim, 1, bm), data.dtype),
-        interpret=resolve_interpret(interpret),
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel",),
-        ),
-    )(data, xg)
-    return y.reshape(b_dim, bm)
+    with jax.named_scope(KERNEL):
+        y = pl.pallas_call(
+            functools.partial(_kernel, semiring=semiring),
+            name="spmv_ell_pallas",
+            grid=(b_dim,),
+            in_specs=[block, block],
+            out_specs=pl.BlockSpec((1, 1, bm), lambda b: (b, 0, 0)),
+            out_shape=jax.ShapeDtypeStruct((b_dim, 1, bm), data.dtype),
+            interpret=resolve_interpret(interpret),
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("parallel",),
+            ),
+        )(data, xg)
+        return y.reshape(b_dim, bm)

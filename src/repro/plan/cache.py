@@ -13,11 +13,12 @@ from __future__ import annotations
 import functools
 import hashlib
 import threading
-import time
 from collections import OrderedDict
 from typing import Callable, Dict
 
 import numpy as np
+
+from repro.spans import span
 
 from .fingerprint import fingerprint_arrays, matrix_fingerprint
 
@@ -165,9 +166,9 @@ class PlanCache:
                 self._plans.move_to_end(key)
                 self.hits += 1
                 return self._plans[key]
-        t0 = time.perf_counter()
-        value = builder()          # build outside the lock (can be slow)
-        elapsed = time.perf_counter() - t0
+        with span("plan_cache.build") as build:
+            value = builder()      # build outside the lock (can be slow)
+        elapsed = build.seconds
         with self._lock:
             if key not in self._plans:
                 self.misses += 1
@@ -192,11 +193,14 @@ class PlanCache:
 
     def get_or_compile(self, matrix, **opts):
         """The main entry: `compile`d plan for (matrix contents, opts),
-        cached.  Same signature as `repro.plan.compile`."""
+        cached.  Same signature as `repro.plan.compile`.  Runs in a
+        `plan_cache.get` span, the key's digest in `plan.fingerprint`."""
         from .compiler import compile as _compile
 
-        key = self.key_for(matrix, **opts)
-        return self.get_or_build(key, lambda: _compile(matrix, **opts))
+        with span("plan_cache.get"):
+            with span("plan.fingerprint"):
+                key = self.key_for(matrix, **opts)
+            return self.get_or_build(key, lambda: _compile(matrix, **opts))
 
     def invalidate(self, matrix_or_fingerprint) -> int:
         """Drop every plan for the given matrix (any options).  Returns the

@@ -46,17 +46,19 @@ Predictors (`predictor=`):
 
 `compile_stats['scoring']` records the *resolved* mode ('model',
 'replay', 'analytic', or 'none'), which is what `PlanCache` buckets its
-predictor-vs-oracle compile counters by.
+predictor-vs-oracle compile counters by.  Its `*_s` keys are the seconds
+of the compile's phase spans (`plan.reorder`, `plan.analyze`,
+`plan.predict`, `plan.convert`, `plan.prepare`, inside `plan.compile`).
 """
 from __future__ import annotations
 
-import time
 from typing import Dict, Optional
 
 from repro.core import structure
 from repro.core.cache_model import SANDY_BRIDGE, MachineModel
 from repro.core.formats import BELL, CSR, DIA, ELL, HYB
 from repro.kernels import _layout as kl
+from repro.spans import span, spanned
 
 from .fingerprint import matrix_fingerprint
 from .plan import SpmvPlan
@@ -195,6 +197,7 @@ def _predict(csr: CSR, threads: int, machine: MachineModel,
     raise ValueError(f"unknown predictor {predictor!r}")
 
 
+@spanned("plan.compile")
 def compile(matrix: CSR, *,                       # noqa: A001 (plan.compile)
             threads: int = 1,
             mesh=None,
@@ -283,11 +286,10 @@ def compile(matrix: CSR, *,                       # noqa: A001 (plan.compile)
     if predictor == "oracle":
         predictor = "replay" if matrix.nnz <= REPLAY_NNZ_MAX else "analytic"
 
-    t0 = time.perf_counter()
-    cands = _candidates(matrix, reorder)
-    permuted_by = {label: (r.apply(matrix) if r is not None else matrix)
-                   for label, r in cands.items()}
-    stats["reorder_s"] = time.perf_counter() - t0
+    with span("plan.reorder", stats, "reorder_s"):
+        cands = _candidates(matrix, reorder)
+        permuted_by = {label: (r.apply(matrix) if r is not None else matrix)
+                       for label, r in cands.items()}
 
     # Candidate enumeration: one (format, reordering) pair per reordering
     # candidate, the format read off that candidate's permuted structure
@@ -297,18 +299,17 @@ def compile(matrix: CSR, *,                       # noqa: A001 (plan.compile)
     # runs and processes, keeping fingerprint-salted cache entries stable.
     fmt_by: Dict[str, str] = {}
     report_by: Dict[str, object] = {}
-    t0 = time.perf_counter()
-    for label in sorted(cands):
-        if format is not None:
-            fmt_by[label], report_by[label] = format, None
-        else:
-            rep = structure.analyze(permuted_by[label],
-                                    sample_rows=sample_rows)
-            report_by[label] = rep
-            fmt_by[label] = choose_format(rep, threads=threads,
-                                          semiring_safe=sr is not None)
-    if format is None:
-        stats["analyze_s"] = time.perf_counter() - t0
+    with span("plan.analyze", stats if format is None else None,
+              "analyze_s"):
+        for label in sorted(cands):
+            if format is not None:
+                fmt_by[label], report_by[label] = format, None
+            else:
+                rep = structure.analyze(permuted_by[label],
+                                        sample_rows=sample_rows)
+                report_by[label] = rep
+                fmt_by[label] = choose_format(rep, threads=threads,
+                                              semiring_safe=sr is not None)
     ordered = sorted(cands, key=lambda lab: (fmt_by[lab], lab))
 
     if len(ordered) > 1:
@@ -327,50 +328,49 @@ def compile(matrix: CSR, *,                       # noqa: A001 (plan.compile)
         keep = set(seen.values())
         ordered = [lab for lab in ordered if lab in keep]
 
-    t0 = time.perf_counter()
-    predicted: Dict[str, Dict] = {}
-    if predictor == "none" or len(ordered) == 1:
-        chosen = ordered[0]
-        stats["scoring"] = "none"
-    else:
-        if predictor == "model":
-            import numpy as _np
-
-            from .costmodel import features_for
-
-            l2b = getattr(parallel_spec, "l2_bytes", None)
-            llcb = getattr(parallel_spec, "llc_bytes", None)
-            feats = []
-            for label in ordered:
-                rep = report_by[label]
-                if rep is None:
-                    # format was forced, so the loop above skipped the
-                    # analysis -- the model still needs features
-                    rep = structure.analyze(permuted_by[label],
-                                            sample_rows=sample_rows)
-                    report_by[label] = rep
-                feats.append(features_for(rep, threads, l2_bytes=l2b,
-                                          llc_bytes=llcb, machine=machine))
-            scores = model.predict(_np.stack(feats))
-            for label, yhat in zip(ordered, scores):
-                predicted[label] = {"predictor": "model",
-                                    "gflops": float(2.0 ** yhat)}
+    with span("plan.predict", stats, "predict_s"):
+        predicted: Dict[str, Dict] = {}
+        if predictor == "none" or len(ordered) == 1:
+            chosen = ordered[0]
+            stats["scoring"] = "none"
         else:
-            for label in ordered:
-                predicted[label] = _predict(permuted_by[label], threads,
-                                            machine, parallel_spec,
-                                            predictor)
-        chosen = ordered[0]
-        for label in ordered[1:]:       # strict >: ties keep sorted order
-            if predicted[label]["gflops"] > predicted[chosen]["gflops"]:
-                chosen = label
-        if chosen != "none" and "none" in predicted:
-            # reordered winners must clear the transport margin
-            bar = predicted["none"]["gflops"] * (1.0 + REORDER_MARGIN)
-            if predicted[chosen]["gflops"] <= bar:
-                chosen = "none"
-        stats["scoring"] = predictor
-    stats["predict_s"] = time.perf_counter() - t0
+            if predictor == "model":
+                import numpy as _np
+
+                from .costmodel import features_for
+
+                l2b = getattr(parallel_spec, "l2_bytes", None)
+                llcb = getattr(parallel_spec, "llc_bytes", None)
+                feats = []
+                for label in ordered:
+                    rep = report_by[label]
+                    if rep is None:
+                        # format was forced, so the loop above skipped the
+                        # analysis -- the model still needs features
+                        rep = structure.analyze(permuted_by[label],
+                                                sample_rows=sample_rows)
+                        report_by[label] = rep
+                    feats.append(features_for(rep, threads, l2_bytes=l2b,
+                                              llc_bytes=llcb, machine=machine))
+                scores = model.predict(_np.stack(feats))
+                for label, yhat in zip(ordered, scores):
+                    predicted[label] = {"predictor": "model",
+                                        "gflops": float(2.0 ** yhat)}
+            else:
+                for label in ordered:
+                    predicted[label] = _predict(permuted_by[label], threads,
+                                                machine, parallel_spec,
+                                                predictor)
+            chosen = ordered[0]
+            for label in ordered[1:]:       # strict >: ties keep sorted order
+                if predicted[label]["gflops"] > predicted[chosen]["gflops"]:
+                    chosen = label
+            if chosen != "none" and "none" in predicted:
+                # reordered winners must clear the transport margin
+                bar = predicted["none"]["gflops"] * (1.0 + REORDER_MARGIN)
+                if predicted[chosen]["gflops"] <= bar:
+                    chosen = "none"
+            stats["scoring"] = predictor
 
     reordering, permuted = cands[chosen], permuted_by[chosen]
     report, format_name = report_by[chosen], fmt_by[chosen]
@@ -382,15 +382,13 @@ def compile(matrix: CSR, *,                       # noqa: A001 (plan.compile)
                                 interpret=interpret, stats=stats,
                                 keep_csr=keep_csr)
 
-    t0 = time.perf_counter()
-    container = convert(permuted, format_name, fill=pad_value)
-    stats["convert_s"] = time.perf_counter() - t0
+    with span("plan.convert", stats, "convert_s"):
+        container = convert(permuted, format_name, fill=pad_value)
 
-    t0 = time.perf_counter()
-    prep = _prepare(container, format_name, bn=bn, bm=bm,
-                    n_stripes=n_stripes, seg_len=seg_len,
-                    pad_value=pad_value) if use_pallas else None
-    stats["prepare_s"] = time.perf_counter() - t0
+    with span("plan.prepare", stats, "prepare_s"):
+        prep = _prepare(container, format_name, bn=bn, bm=bm,
+                        n_stripes=n_stripes, seg_len=seg_len,
+                        pad_value=pad_value) if use_pallas else None
 
     return SpmvPlan(
         fingerprint=fp, format_name=format_name, container=container,
@@ -407,11 +405,10 @@ def _compile_sharded(fp, permuted, reordering, report, mesh, partition, *,
     `shard_map` Pallas ELL kernel is the executor."""
     from repro.distributed.spmv import default_row_partition
 
-    t0 = time.perf_counter()
-    if partition is None:
-        partition = default_row_partition(permuted, mesh)
-    prep = kl.prepare_ell_shards(permuted, partition, bm=bm)
-    stats["prepare_s"] = time.perf_counter() - t0
+    with span("plan.prepare", stats, "prepare_s"):
+        if partition is None:
+            partition = default_row_partition(permuted, mesh)
+        prep = kl.prepare_ell_shards(permuted, partition, bm=bm)
     return SpmvPlan(
         fingerprint=fp, format_name="ell-sharded", container=None,
         prep=prep, reordering=reordering, report=report,

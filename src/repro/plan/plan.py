@@ -35,6 +35,7 @@ import jax.numpy as jnp
 
 from repro.core.formats import BELL, CSR, DIA, ELL, HYB
 from repro.kernels import _layout as kl
+from repro.spans import span
 
 # format name -> jitted `(prep, x, interpret=, [semiring=])` runner
 RUNNERS = {
@@ -108,12 +109,14 @@ class SpmvPlan:
 
     def execute(self, x: jax.Array, interpret: Optional[bool] = None
                 ) -> jax.Array:
-        """y = A @ x through the frozen plan (original row/col order)."""
-        x = jnp.asarray(x)
-        if self.reordering is not None:
-            y = self._run(self.reordering.permute_x(x), interpret)
-            return self.reordering.restore_y(y)
-        return self._run(x, interpret)
+        """y = A @ x through the frozen plan (original row/col order),
+        inside an `spmv.execute` span."""
+        with span("spmv.execute", format=self.format_name):
+            x = jnp.asarray(x)
+            if self.reordering is not None:
+                y = self._run(self.reordering.permute_x(x), interpret)
+                return self.reordering.restore_y(y)
+            return self._run(x, interpret)
 
     __call__ = execute
 

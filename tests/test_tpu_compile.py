@@ -3,8 +3,9 @@
 Nothing runs: each test lowers a plan runner at 2^22-row shapes with
 `interpret=False` and compiles it for a v5e chip that is described, not
 attached, so what Mosaic or the device memory would refuse fails here.
-Each compiled program must hold a Mosaic kernel (`tpu_custom_call`) and
-fit one chip's 16 GB of HBM.  The topology is described in a fixture,
+Each compiled program must hold a Mosaic kernel (`tpu_custom_call`), put
+each op it wrote in one stage scope (`repro.spans`), and fit one chip's
+16 GB of HBM.  The topology is described in a fixture,
 never at import, and the persistent compilation cache is off around the
 compiles (a TPU entry written here could not be read back).
 """
@@ -24,6 +25,7 @@ from jax.sharding import (NamedSharding, PartitionSpec,       # noqa: E402
 
 from repro.graph.semiring import MIN_PLUS, OR_AND             # noqa: E402
 from repro.kernels import _layout as kl                       # noqa: E402
+from repro.spans import scope_of, staged_ops                  # noqa: E402
 
 N = 1 << 22            # rows of the chip smoke's FD and R-MAT matrices
 HBM_BYTES = 16e9       # one v5e chip
@@ -96,7 +98,11 @@ def _layouts(sharding):
 
 
 def _check(compiled):
-    assert "tpu_custom_call" in compiled.as_text()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    # each op the program wrote lies in one stage scope
+    for instr, op_name in staged_ops(text).items():
+        assert scope_of(op_name) is not None, (instr, op_name)
     mem = compiled.memory_analysis()
     total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
              + mem.temp_size_in_bytes)
