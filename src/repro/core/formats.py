@@ -305,19 +305,34 @@ class DIA:
         return (self.n_rows, self.n_cols)
 
     @staticmethod
-    def from_csr(csr: CSR) -> "DIA":
+    def from_csr(csr: CSR, max_diags: int | None = None) -> DIA | None:
+        """DIA of `csr`; with `max_diags`, None when the matrix holds more
+        diagonals than that, found before the (n_diags, n_rows) band is
+        allocated.  Linear in nnz: the diagonals present are counted in a
+        table indexed by offset, not found by sorting the offsets."""
+        n = csr.n_rows
         indptr = np.asarray(csr.indptr)
-        cols = np.asarray(csr.indices)
         vals = np.asarray(csr.data)
-        rows = np.repeat(np.arange(csr.n_rows), np.diff(indptr))
-        offs = cols.astype(np.int64) - rows
-        uniq = np.unique(offs)
-        data = np.zeros((len(uniq), csr.n_rows), dtype=vals.dtype)
-        np.add.at(data, (np.searchsorted(uniq, offs), rows), vals)
+        rows = np.repeat(np.arange(n, dtype=np.int64), np.diff(indptr))
+        # offset + n - 1 of each nonzero: an index into [0, n + n_cols - 1)
+        slot = np.asarray(csr.indices).astype(np.int64)
+        slot -= rows
+        slot += n - 1
+        n_slots = n + csr.n_cols - 1
+        present = np.flatnonzero(np.bincount(slot, minlength=n_slots))
+        if max_diags is not None and present.size > max_diags:
+            return None
+        diag_of = np.zeros(n_slots, dtype=np.int64)
+        diag_of[present] = np.arange(present.size)
+        flat = diag_of[slot]
+        flat *= n
+        flat += rows
+        data = np.zeros(present.size * n, dtype=vals.dtype)
+        np.add.at(data, flat, vals)
         return DIA(
-            data=jnp.asarray(data),
-            offsets=jnp.asarray(uniq.astype(np.int32)),
-            n_rows=csr.n_rows, n_cols=csr.n_cols,
+            data=jnp.asarray(data.reshape(present.size, n)),
+            offsets=jnp.asarray((present - (n - 1)).astype(np.int32)),
+            n_rows=n, n_cols=csr.n_cols,
         )
 
     @property

@@ -116,7 +116,8 @@ def auto_format(csr: CSR, report: structure.StructureReport | None = None,
         csr = reordering.apply(csr)
         report = None
     rep = report or structure.analyze(csr)
-    return _plan.convert(csr, _plan.choose_format(rep, threads=threads))
+    return _plan.convert_chosen(
+        csr, _plan.choose_format(rep, threads=threads))[1]
 
 
 def spmv(matrix, x: jax.Array, use_pallas: bool = False,

@@ -35,7 +35,7 @@ Quick use:
 """
 from .cache import DEFAULT_CACHE, PlanCache, get_plan
 from .compiler import (REPLAY_NNZ_MAX, choose_format, compile, convert,
-                       plan_for_container)
+                       convert_chosen, plan_for_container)
 from .costmodel import (CostModel, default_model, fit_cost_model,
                         set_default_model)
 from .fingerprint import (chain_fingerprint, delta_fingerprint,
@@ -51,7 +51,7 @@ compile_plan = compile
 
 __all__ = [
     "SpmvPlan", "compile", "compile_plan", "plan_for_container",
-    "choose_format", "convert", "REPLAY_NNZ_MAX",
+    "choose_format", "convert", "convert_chosen", "REPLAY_NNZ_MAX",
     "PlanCache", "DEFAULT_CACHE", "get_plan",
     "CostModel", "fit_cost_model", "default_model", "set_default_model",
     "OverlaidPlan", "overlay", "overlay_eligible",

@@ -87,6 +87,10 @@ SEG_MIN_CV = 0.5
 # formats (DIA bands, BELL tiles) cannot express -- see graph.semiring.
 SEMIRING_FORMATS = ("csr", "csr-seg", "ell", "hyb")
 
+# Most diagonals a chosen DIA band may hold: the analysis counts the
+# sampled rows' offsets, `convert_chosen` the whole matrix's.
+DIA_MAX_DIAGS = 64
+
 
 def choose_format(report, threads: int = 1,
                   semiring_safe: bool = False) -> str:
@@ -99,7 +103,8 @@ def choose_format(report, threads: int = 1,
     (`SEMIRING_FORMATS`), with ELL replacing the dense-footprint picks.
     """
     if not semiring_safe:
-        if report.kind == "banded" and report.n_distinct_offsets <= 64:
+        if (report.kind == "banded"
+                and report.n_distinct_offsets <= DIA_MAX_DIAGS):
             return "dia"
         if report.kind == "blocked":
             return "bell"
@@ -128,6 +133,19 @@ def convert(csr: CSR, format_name: str, fill: float = 0.0):
     if format_name in ("csr", "csr-seg"):
         return csr
     raise ValueError(f"unknown format {format_name!r}")
+
+
+def convert_chosen(csr: CSR, format_name: str, fill: float = 0.0):
+    """`convert` for a format `choose_format` picked: (format, container).
+
+    The structure analysis samples rows, so a matrix it calls banded can
+    hold diagonals in the rows it skipped.  A DIA pick whose whole matrix
+    holds more than `DIA_MAX_DIAGS` diagonals keeps CSR, as a banded
+    report over that count does, and no band is allocated."""
+    if format_name == "dia":
+        dia = DIA.from_csr(csr, max_diags=DIA_MAX_DIAGS)
+        return ("dia", dia) if dia is not None else ("csr", csr)
+    return format_name, convert(csr, format_name, fill=fill)
 
 
 def _prepare(container, format_name: str, *, bn: int, bm: int,
@@ -383,7 +401,11 @@ def compile(matrix: CSR, *,                       # noqa: A001 (plan.compile)
                                 keep_csr=keep_csr)
 
     with span("plan.convert", stats, "convert_s"):
-        container = convert(permuted, format_name, fill=pad_value)
+        if format is None:
+            format_name, container = convert_chosen(permuted, format_name,
+                                                    fill=pad_value)
+        else:
+            container = convert(permuted, format_name, fill=pad_value)
 
     with span("plan.prepare", stats, "prepare_s"):
         prep = _prepare(container, format_name, bn=bn, bm=bm,

@@ -58,6 +58,33 @@ def test_geometry_reaches_features():
 # fit determinism + shipped-artifact integrity (what CI re-checks)
 # ---------------------------------------------------------------------------
 
+KIND_FEATURES = [cm.FEATURE_NAMES.index(k) for k in
+                 ("kind_banded", "kind_blocked", "kind_unstructured")]
+
+
+@pytest.mark.parametrize("kind", cm.LABEL_KINDS)
+def test_structure_kind_matches_corpus_rows(corpus, kind):
+    """The shipped model was fitted on stored feature rows: the structure
+    analysis must still classify every label matrix (each size, seed and
+    reordering in the corpus) as its row's `kind_*` one-hot says."""
+    from repro.core import structure
+    from repro.reorder import STRATEGIES
+
+    stored = {}
+    for r in corpus:
+        if r.kind == kind:
+            oh = tuple(r.features[i] for i in KIND_FEATURES)
+            stored.setdefault((r.log2n, r.seed, r.reorder), set()).add(oh)
+    assert {n for n, _, _ in stored} == {8, 9, 10}
+    for (log2n, seed, reorder), onehots in sorted(stored.items()):
+        csr = cm.label_matrix(kind, 2 ** log2n, seed)
+        if reorder != "none":
+            csr = STRATEGIES[reorder](csr).apply(csr)
+        feats = cm.features_for(structure.analyze(csr))
+        assert onehots == {tuple(feats[i] for i in KIND_FEATURES)}, \
+            (log2n, seed, reorder)
+
+
 def test_fit_is_deterministic(corpus):
     sub = corpus[:120]
     cfg = {"n_trees": 12}

@@ -58,6 +58,25 @@ def test_dia_offsets_sorted_unique():
     assert (np.diff(offs) > 0).all()
 
 
+@pytest.mark.parametrize("n,m,nnz", [(13, 17, 60), (17, 13, 60), (1, 5, 4),
+                                     (9, 9, 0)])
+def test_dia_band_matches_dense(n, m, nnz):
+    """Every stored diagonal is the dense matrix's, summed duplicates and
+    rectangular shapes included, and only diagonals holding a nonzero
+    are stored."""
+    rows, cols, vals = random_coo(n, m, nnz, seed=n + m)
+    csr = CSR.from_coo(rows, cols, vals, n, m)
+    dense = np.asarray(csr.to_dense())
+    dia = DIA.from_csr(csr)
+    offs = np.asarray(dia.offsets)
+    np.testing.assert_array_equal(offs, np.unique(cols - rows))
+    band = np.zeros((offs.size, n), np.float32)
+    for k, off in enumerate(offs):
+        i = np.arange(max(0, -off), min(n, m - off))
+        band[k, i] = dense[i, i + off]
+    np.testing.assert_array_equal(np.asarray(dia.data), band)
+
+
 def test_formats_are_pytrees():
     csr = fd_matrix(64)
     leaves = jax.tree.leaves(csr)

@@ -1,6 +1,7 @@
 """Ahead-of-time compiles of the SpMV runners for a described TPU v5e.
 
-Nothing runs: each test lowers a plan runner at 2^22-row shapes with
+Nothing runs: each test lowers a plan runner at 2^22-row shapes (and DIA
+at the benchmark FD cell's 2^24 rows too) with
 `interpret=False` and compiles it for a v5e chip that is described, not
 attached, so what Mosaic or the device memory would refuse fails here.
 Each compiled program must hold a Mosaic kernel (`tpu_custom_call`), put
@@ -28,6 +29,7 @@ from repro.kernels import _layout as kl                       # noqa: E402
 from repro.spans import scope_of, staged_ops                  # noqa: E402
 
 N = 1 << 22            # rows of the chip smoke's FD and R-MAT matrices
+N_FD = 1 << 24         # rows of the benchmark's FD stencil (4096 x 4096)
 HBM_BYTES = 16e9       # one v5e chip
 
 
@@ -62,7 +64,9 @@ def _layouts(sharding):
     """Prepared layouts at the 2^22-row shapes the chip smoke builds:
     FD (9 nnz/row) for DIA, BELL, ELL and CSR (BELL's 12.6 MB
     block-column table runs in SMEM-sized calls), R-MAT (~33M nnz, hub
-    rows) for CSR-seg and HYB."""
+    rows) for CSR-seg and HYB; and 'dia-2p24', the band the plan builds
+    for the 2^24-row periodic stencil (9 interior and 12 wrap
+    diagonals)."""
     def s(shape, dtype=jnp.float32):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
 
@@ -83,6 +87,9 @@ def _layouts(sharding):
         "dia": (kl.spmv_dia_prepared, kl.PreparedDIA(
             band=s((9, N)), offsets=s((9,), i32), n_rows=N, n_cols=N,
             bn=512), N),
+        "dia-2p24": (kl.spmv_dia_prepared, kl.PreparedDIA(
+            band=s((21, N_FD)), offsets=s((21,), i32), n_rows=N_FD,
+            n_cols=N_FD, bn=512), N_FD),
         "bell": (kl.spmv_bell_prepared, kl.PreparedBELL(
             data=s((nbr, 6, 8, 128)), block_cols=s((nbr, 6), i32),
             n_rows=N, n_cols=N, x_pad=N), N),
@@ -110,7 +117,7 @@ def _check(compiled):
 
 
 @pytest.mark.parametrize("fmt,semiring", [
-    ("dia", None), ("bell", None), ("ell", None), ("csr", None),
+    ("dia", None), ("dia-2p24", None), ("bell", None), ("ell", None), ("csr", None),
     ("csr-seg", None), ("hyb", None),
     ("ell", "min_plus"), ("csr-seg", "min_plus"), ("hyb", "or_and"),
 ])
