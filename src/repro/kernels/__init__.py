@@ -5,8 +5,11 @@
   spmv_csr         column-blocked CSR: one-hot segment sum per row block
   spmv_csr_seg     nnz-balanced segmented CSR (merge-CSR)
   spmv_bell        blocked-ELL: data-dependent block-tile gathers (paper P3)
+  spmm             k vectors at once: X's rows gathered a chunk of
+                   nonzeros at a time, reduced into row blocks of Y
   _layout          shared host-side layout prep: `prepare_*` (run once,
-                   at plan-compile time) + `spmv_*_prepared` (zero
+                   at plan-compile time, or for SpMM on a plan's first
+                   `spmm`) + `spmv_*_prepared` / `spmm_prepared` (zero
                    matrix-side work per call)
   ops              per-call wrappers composing prepare + run, plus the
                    attention entry points

@@ -31,8 +31,10 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core.formats import BELL, CSR, DIA, ELL, HYB
-from repro.spans import MERGE, X_PREP
+from repro.spans import (MERGE, SPMM_KERNEL, SPMM_MERGE, SPMM_X_GATHER,
+                         SPMM_X_PREP, X_PREP)
 
+from . import spmm as _spmm
 from . import spmv_bell as _bell
 from . import spmv_csr as _csr
 from . import spmv_csr_seg as _seg
@@ -461,6 +463,102 @@ def spmv_hyb_prepared(prep: PreparedHYB, x: jax.Array,
         return semiring.add(y_light, y_heavy)
 
 
+# ---------------------------------------------------------------------------
+# SpMM (k vectors at once, features last: row-blocked chunks of nonzeros)
+# ---------------------------------------------------------------------------
+
+@_pytree("cols", "vals", "rloc", "blk", "first")
+@dataclasses.dataclass(frozen=True)
+class PreparedSpMM:
+    """Row blocks of `bm` rows, each block's nonzeros padded to whole
+    chunks of `chunk` slots, the chunks in whole groups of `group`
+    (`kernels.spmm` describes the arrays)."""
+    cols: jax.Array      # (T, chunk) int32
+    vals: jax.Array      # (T, chunk)
+    rloc: jax.Array      # (T, chunk) int32 row within the block
+    blk: jax.Array       # (T,) int32 row block of each chunk
+    first: jax.Array     # (T,) int32 1 on each block's first chunk
+    n_rows: int
+    n_cols: int
+    bm: int
+    chunk: int
+    group: int
+
+    @property
+    def n_blocks(self) -> int:
+        return max(ceil_div(self.n_rows, self.bm), 1)
+
+
+def prepare_spmm(csr: CSR, bm: int = 128, chunk: int = 512,
+                 group: int = 512) -> PreparedSpMM:
+    """Cut the CSR rows into blocks of `bm` and pad each block's
+    nonzeros, in row-major order, to whole chunks (at least one, so that
+    every block of Y is written); the chunk count is padded to whole
+    groups of `group` (fewer, in steps of 8, for a small matrix) with
+    all-padding chunks on the last block."""
+    indptr = np.asarray(csr.indptr, dtype=np.int64)
+    n_rows = int(csr.n_rows)
+    n_blocks = max(ceil_div(n_rows, bm), 1)
+    bounds = indptr[np.minimum(np.arange(n_blocks + 1) * bm, n_rows)]
+    per_block = np.diff(bounds)
+    chunks = np.maximum(-(-per_block // chunk), 1)
+    cstart = np.zeros(n_blocks + 1, dtype=np.int64)
+    np.cumsum(chunks, out=cstart[1:])
+    n_chunks = int(cstart[-1])
+    group = min(group, round_up(n_chunks, _spmm.SUBLANES))
+    total = round_up(n_chunks, group)
+    nnz = int(bounds[-1])
+    # a nonzero's slot: its block's first slot plus its place in the block
+    slot = np.arange(nnz, dtype=np.int64)
+    slot += np.repeat(cstart[:-1] * chunk - bounds[:-1], per_block)
+    rows = np.repeat(np.arange(n_rows, dtype=np.int64), np.diff(indptr))
+    data = np.asarray(csr.data)
+    cols = np.zeros(total * chunk, dtype=np.int32)
+    vals = np.zeros(total * chunk, dtype=data.dtype)
+    rloc = np.zeros(total * chunk, dtype=np.int32)
+    cols[slot] = np.asarray(csr.indices)
+    vals[slot] = data
+    rloc[slot] = rows % bm
+    blk = np.full(total, n_blocks - 1, dtype=np.int32)
+    blk[:n_chunks] = np.repeat(np.arange(n_blocks, dtype=np.int32), chunks)
+    first = np.zeros(total, dtype=np.int32)
+    first[cstart[:-1]] = 1
+    return PreparedSpMM(
+        cols=jnp.asarray(cols.reshape(total, chunk)),
+        vals=jnp.asarray(vals.reshape(total, chunk)),
+        rloc=jnp.asarray(rloc.reshape(total, chunk)),
+        blk=jnp.asarray(blk), first=jnp.asarray(first), n_rows=n_rows,
+        n_cols=int(csr.n_cols), bm=bm, chunk=chunk, group=group)
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def spmm_prepared(prep: PreparedSpMM, x: jax.Array,
+                  interpret: bool | None = None) -> jax.Array:
+    """Y = A X for x of shape (n_cols, k): one loop over the groups of
+    chunks, each an XLA row gather of X and one kernel call."""
+    g = prep.group
+    with jax.named_scope(SPMM_X_PREP):
+        x = x.astype(prep.vals.dtype)
+        y = jnp.zeros((prep.n_blocks * prep.bm, x.shape[1]), x.dtype)
+
+    def step(i, y):
+        c0 = i * g
+        with jax.named_scope(SPMM_X_GATHER):
+            cols = jax.lax.dynamic_slice_in_dim(prep.cols, c0, g)
+            xg = jnp.take(x, cols.reshape(-1), axis=0, mode="clip")
+        with jax.named_scope(SPMM_KERNEL):
+            blk = jax.lax.dynamic_slice_in_dim(prep.blk, c0, g)
+            first = jax.lax.dynamic_slice_in_dim(prep.first, c0, g)
+            g0 = jnp.reshape(c0, (1,)).astype(jnp.int32)
+        return _spmm.spmm_rows_pallas(g0, blk, first, xg, prep.vals,
+                                      prep.rloc, y, bm=prep.bm,
+                                      interpret=interpret)
+
+    y = jax.lax.fori_loop(0, prep.blk.shape[0] // g, step, y)
+    with jax.named_scope(SPMM_MERGE):
+        return y[: prep.n_rows]
+
+
 __all__ = [
     "ceil_div", "round_up",
     "PreparedDIA", "prepare_dia", "spmv_dia_prepared",
@@ -470,4 +568,5 @@ __all__ = [
     "PaddedCSR", "prepare_csr", "spmv_csr_prepared",
     "PreparedSegCSR", "prepare_csr_seg", "spmv_csr_seg_prepared",
     "PreparedHYB", "prepare_hyb", "spmv_hyb_prepared",
+    "PreparedSpMM", "prepare_spmm", "spmm_prepared",
 ]

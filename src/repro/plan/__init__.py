@@ -11,8 +11,9 @@ This package freezes that decision chain once per matrix:
                scored by predicted contended-LLC throughput, winning
                format converted, kernel layout pre-padded; `semiring=`
                builds absorbing-padded plans for `repro.graph` analytics
-  plan         SpmvPlan: execute / execute_many (SpMM) /
-               power_iteration / address_trace
+  plan         SpmvPlan: execute / spmm (k vectors, features last) /
+               execute_many (vectors as rows) / power_iteration /
+               address_trace
   overlay      OverlaidPlan: a frozen plan + edge delta served warm
                (streaming matrices; staleness-budgeted re-plan)
   cache        PlanCache + the process-wide DEFAULT_CACHE behind the
@@ -29,7 +30,8 @@ Quick use:
     from repro import plan
     p = plan.compile(csr, threads=8)       # slow: analyze+predict+convert
     y = p.execute(x)                       # fast: zero per-call prep
-    Y = p.execute_many(X)                  # batched SpMM
+    Y = p.spmm(X)                          # SpMM: X (n_cols, k)
+    Y = p.execute_many(X.T)                # the same, (k, n_cols)
     lam, v = p.power_iteration(x0)         # amortized iterative driver
     plan.save_plan(p, "ckpt/")             # survives restart
 """

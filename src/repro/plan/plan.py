@@ -13,9 +13,14 @@ Repeated-traffic surfaces built on a plan:
   * `execute(x)`        one multiply, bit-identical to the per-call
                         `core.spmv.spmv(fmt, x, use_pallas=True)` path
                         (same prepared layout, same kernel);
-  * `execute_many(X)`   batched multi-vector SpMV (SpMM): the vectorized
-                        jnp format kernel vmapped over the leading axis
-                        of X, jitted once per plan;
+  * `spmm(X)`           k vectors at once, features last: Y = A @ X for X
+                        of shape (n_cols, k), through the row-gather
+                        Pallas kernel over a layout built on the first
+                        call (`prepare_spmm`); plus-times plans only;
+  * `execute_many(X)`   the same product with the vectors as rows,
+                        (k, n_cols): `spmm(X.T).T` on plus-times plans,
+                        the semiring jnp kernel vmapped over the rows of
+                        X on semiring plans;
   * `power_iteration`   iterative driver (paper §I: repeated SpMV drives
                         eigensolvers) that amortizes one plan across all
                         iterations;
@@ -28,6 +33,7 @@ Plans serialize through `repro.plan.serial` (backed by
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Any, Dict, Optional
 
 import jax
@@ -82,6 +88,8 @@ class SpmvPlan:
     compile_stats: Dict[str, float] = dataclasses.field(default_factory=dict)
     mesh: Any = None                 # sharded plans only; never serialized
     _many_fn: Any = dataclasses.field(default=None, repr=False, compare=False)
+    _spmm_prep: Any = dataclasses.field(default=None, repr=False,
+                                        compare=False)
     _traces: Dict = dataclasses.field(default_factory=dict, repr=False,
                                       compare=False)
 
@@ -179,33 +187,84 @@ class SpmvPlan:
 
     # -- repeated-traffic surfaces ------------------------------------------
 
-    def execute_many(self, X: jax.Array) -> jax.Array:
-        """Batched multi-vector SpMV (SpMM path): Y[k] = A @ X[k].
+    def prepare_spmm(self):
+        """The SpMM layout (`kernels._layout.PreparedSpMM`) of the plan's
+        (permuted) CSR, built on the first call, inside a
+        `plan.prepare_spmm` span that adds its seconds to
+        `compile_stats["prepare_spmm_s"]`."""
+        if self._spmm_prep is None:
+            csr = self.csr if self.csr is not None else (
+                self.container if isinstance(self.container, CSR) else None)
+            if csr is None:
+                raise ValueError(
+                    "plan retains no CSR (compiled with keep_csr=False); "
+                    "recompile with keep_csr=True to use the SpMM path")
+            with span("plan.prepare_spmm", self.compile_stats,
+                      "prepare_spmm_s"):
+                self._spmm_prep = kl.prepare_spmm(csr)
+        return self._spmm_prep
 
-        Uses the vectorized jnp format kernel vmapped over the leading
-        axis (one fused SpMM, not a Python loop of Pallas launches),
-        jitted once per plan and reused across calls.  Matches
-        `execute` per vector up to float summation-order tolerance.
-        """
+    def spmm(self, X: jax.Array, interpret: Optional[bool] = None
+             ) -> jax.Array:
+        """Y = A @ X for k vectors at once, features last: X is
+        (n_cols, k) and Y is (n_rows, k), in the original row and column
+        order, inside an `spmm.execute` span.  Plus-times plans only;
+        the row-gather Pallas kernel runs whatever the plan's format."""
+        with span("spmm.execute", format=self.format_name):
+            if self._semiring() is not None:
+                raise ValueError(f"spmm is plus-times only; this plan runs "
+                                 f"{self.semiring!r} (use execute_many)")
+            X = jnp.asarray(X)
+            if X.ndim != 2 or X.shape[0] != self.n_cols:
+                raise ValueError(f"spmm expects (n_cols, k) = "
+                                 f"({self.n_cols}, k), got {X.shape}")
+            prep = self.prepare_spmm()
+            if X.shape[1] == 0:
+                return jnp.zeros((self.n_rows, 0), prep.vals.dtype)
+            if self.reordering is not None:
+                X = self.reordering.permute_x(X)
+            Y = kl.spmm_prepared(
+                prep, X,
+                interpret=self.interpret if interpret is None else interpret)
+            if self.reordering is not None:
+                return self.reordering.restore_y(Y)
+            return Y
+
+    def execute_many(self, X: jax.Array) -> jax.Array:
+        """Batched multi-vector SpMV: Y[k] = A @ X[k] for X of shape
+        (k, n_cols).  Plus-times plans run `spmm(X.T).T`; semiring plans
+        run the semiring jnp kernel vmapped over the rows of X, jitted
+        once per plan with the container's arrays as arguments."""
         X = jnp.asarray(X)
         if X.ndim != 2:
             raise ValueError(f"execute_many expects (k, n_cols), got {X.shape}")
+        if self._semiring() is None:
+            return self.spmm(X.T).T
         if self._many_fn is None:
             self._many_fn = self._build_many()
         return self._many_fn(X)
 
     def _build_many(self):
-        base = self._jnp_kernel()       # semiring-aware one-vector body
+        container = self._source_container()
+        sr = self._semiring()
+        perm = None
         if self.reordering is not None:
-            cp = jnp.asarray(self.reordering.col_perm)
-            irp = jnp.asarray(self.reordering.inv_row_perm)
+            perm = (jnp.asarray(self.reordering.col_perm),
+                    jnp.asarray(self.reordering.inv_row_perm))
+
+        @jax.jit
+        def many(container, perm, X):
+            from repro.graph.semiring import spmv_semiring_jnp
 
             def one(xv):
-                return jnp.take(base(jnp.take(xv, cp, axis=0)),
-                                irp, axis=0)
-        else:
-            one = base
-        return jax.jit(jax.vmap(one))
+                if perm is None:
+                    return spmv_semiring_jnp(container, xv, sr)
+                cp, irp = perm
+                y = spmv_semiring_jnp(container, jnp.take(xv, cp, axis=0), sr)
+                return jnp.take(y, irp, axis=0)
+            return jax.vmap(one)(X)
+
+        return functools.partial(many, container, perm)
 
     def power_iteration(self, x0: jax.Array, n_iters: int = 16):
         """Dominant-eigenpair driver over the cached plan (paper §I's

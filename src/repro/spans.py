@@ -15,6 +15,15 @@ Two kinds of name, both recorded by `jax.profiler` on one clock:
                    final slices
     spmv.stitch    the row-sharded path's concatenation of the y slabs
 
+  The multi-vector runner (`kernels._layout.spmm_prepared`) names its
+  stages apart (`SPMM_SCOPES`), so that its device time is read apart
+  from the one-vector runners':
+
+    spmm.x_prep    X's cast and the output's allocation
+    spmm.x_gather  the XLA row gather of X for one group of chunks
+    spmm.kernel    the Pallas segment-sum call
+    spmm.merge     the final row slice of Y
+
 * **Host spans** (`SPANS`) are `jax.profiler.TraceAnnotation`s around
   host steps, opened with `span`.  A `span` also times itself: it adds
   its seconds to a `stats` dict when given one, and to every dict that a
@@ -31,6 +40,11 @@ Two kinds of name, both recorded by `jax.profiler` on one clock:
     plan_cache.build  a plan cache miss: the builder's run
     plan.compile      `plan.compile`, with one span per phase below
     plan.reorder, plan.analyze, plan.predict, plan.convert, plan.prepare
+
+  and for the multi-vector path (`SPMM_SPANS`):
+
+    spmm.execute       `SpmvPlan.spmm`: dispatch of one multiply of k vectors
+    plan.prepare_spmm  the SpMM layout's build, on a plan's first `spmm`
 
 With no profiler running a span costs a few microseconds of host time;
 the scopes are metadata and cost nothing on the device.
@@ -53,11 +67,18 @@ MERGE = "spmv.merge"
 STITCH = "spmv.stitch"
 SCOPES = (X_PREP, X_GATHER, KERNEL, MERGE, STITCH)
 
+SPMM_X_PREP = "spmm.x_prep"
+SPMM_X_GATHER = "spmm.x_gather"
+SPMM_KERNEL = "spmm.kernel"
+SPMM_MERGE = "spmm.merge"
+SPMM_SCOPES = (SPMM_X_PREP, SPMM_X_GATHER, SPMM_KERNEL, SPMM_MERGE)
+
 SPANS = ("spmv.execute", "graph.query", "graph.operand", "graph.iteration",
          "graph.host_sync", "graph.advance", "plan_cache.get",
          "plan.fingerprint", "plan_cache.build", "plan.compile",
          "plan.reorder", "plan.analyze", "plan.predict", "plan.convert",
          "plan.prepare")
+SPMM_SPANS = ("spmm.execute", "plan.prepare_spmm")
 
 _SINKS: contextvars.ContextVar[tuple] = contextvars.ContextVar(
     "repro_span_sinks", default=())
@@ -118,11 +139,11 @@ def collect() -> Iterator[Dict[str, float]]:
         _SINKS.reset(token)
 
 
-def scope_of(op_name: str) -> Optional[str]:
-    """The stage scope an HLO op's `op_name` lies in, or None when it
-    lies in none or in more than one.  (XLA joins the names of ops it
-    merges with ';'.)"""
-    found = {part for part in re.split(r"[/;]", op_name) if part in SCOPES}
+def scope_of(op_name: str, scopes: tuple = SCOPES) -> Optional[str]:
+    """The stage scope of `scopes` an HLO op's `op_name` lies in, or None
+    when it lies in none or in more than one.  (XLA joins the names of
+    ops it merges with ';'.)"""
+    found = {part for part in re.split(r"[/;]", op_name) if part in scopes}
     return found.pop() if len(found) == 1 else None
 
 
@@ -144,4 +165,6 @@ def staged_ops(hlo_text: str) -> Dict[str, str]:
 
 
 __all__ = ["SCOPES", "SPANS", "X_PREP", "X_GATHER", "KERNEL", "MERGE",
-           "STITCH", "span", "spanned", "collect", "scope_of", "staged_ops"]
+           "STITCH", "SPMM_SCOPES", "SPMM_SPANS", "SPMM_X_PREP",
+           "SPMM_X_GATHER", "SPMM_KERNEL", "SPMM_MERGE", "span", "spanned",
+           "collect", "scope_of", "staged_ops"]

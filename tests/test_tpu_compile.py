@@ -1,7 +1,8 @@
 """Ahead-of-time compiles of the SpMV runners for a described TPU v5e.
 
 Nothing runs: each test lowers a plan runner at 2^22-row shapes (and DIA
-at the benchmark FD cell's 2^24 rows too) with
+at the benchmark FD cell's 2^24 rows too, and the SpMM runner at the
+products cell's 2^21 rows and k = 256) with
 `interpret=False` and compiles it for a v5e chip that is described, not
 attached, so what Mosaic or the device memory would refuse fails here.
 Each compiled program must hold a Mosaic kernel (`tpu_custom_call`), put
@@ -13,6 +14,7 @@ compiles (a TPU entry written here could not be read back).
 from __future__ import annotations
 
 import os
+import re
 
 import numpy as np
 import pytest
@@ -26,10 +28,11 @@ from jax.sharding import (NamedSharding, PartitionSpec,       # noqa: E402
 
 from repro.graph.semiring import MIN_PLUS, OR_AND             # noqa: E402
 from repro.kernels import _layout as kl                       # noqa: E402
-from repro.spans import scope_of, staged_ops                  # noqa: E402
+from repro.spans import SPMM_SCOPES, scope_of, staged_ops     # noqa: E402
 
 N = 1 << 22            # rows of the chip smoke's FD and R-MAT matrices
 N_FD = 1 << 24         # rows of the benchmark's FD stencil (4096 x 4096)
+N_GCN = 1 << 21        # vertices of the benchmark's products graph
 HBM_BYTES = 16e9       # one v5e chip
 
 
@@ -145,3 +148,57 @@ def test_row_sharded_ell_compiles_on_four_chips(topo):
     compiled = row_sharded_program(prep, mesh, interpret=False).lower(
         data, idx, x).compile()
     _check(compiled)
+
+
+_LINE = re.compile(r"^\s*(?:ROOT )?%?([\w.\-]+) = (\S+) ([a-z][\w\-]*)\(")
+_OP = re.compile(r'op_name="([^"]*)"')
+
+
+def test_spmm_runner_compiles_to_mosaic_with_its_layout_as_parameters(
+        one_chip):
+    """The products cell's runner: the kernel is a Mosaic call, every
+    array of the prepared layout (the 217,600 chunks of 512 slots that the
+    cell's 107M nonzeros fill) and X are parameters of the program, no
+    constant is larger than a scalar table, and each op the runner wrote lies in one SpMM stage
+    (scalar index arithmetic and the loop's own control left out)."""
+    def s(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    i32, chunks, chunk, k = jnp.int32, 217600, 512, 256
+    prep = kl.PreparedSpMM(
+        cols=s((chunks, chunk), i32), vals=s((chunks, chunk)),
+        rloc=s((chunks, chunk), i32), blk=s((chunks,), i32),
+        first=s((chunks,), i32), n_rows=N_GCN, n_cols=N_GCN, bm=128,
+        chunk=chunk, group=512)
+    compiled = kl.spmm_prepared.lower(prep, s((N_GCN, k)),
+                                      interpret=False).compile()
+    text = compiled.as_text()
+    assert 'custom_call_target="tpu_custom_call"' in text
+    entry = text[text.index("\nENTRY "):]
+    head = entry[: entry.index("{")]
+    for shape in (f"s32[{chunks},{chunk}]", f"f32[{chunks},{chunk}]",
+                  f"s32[{chunks}]", f"f32[{N_GCN},{k}]"):
+        assert shape in head, shape
+    staged = 0
+    for line in text.splitlines():
+        m = _LINE.match(line)
+        if not m:
+            continue
+        shape, opcode = m.group(2), m.group(3)
+        if opcode == "constant":
+            dims = re.search(r"\[([\d,]*)\]", shape).group(1)
+            assert np.prod([int(d) for d in dims.split(",") if d]) <= 1024
+            continue
+        op = _OP.search(line)
+        if opcode in ("parameter", "while", "get-tuple-element", "tuple",
+                      "bitcast", "copy", "copy-start", "copy-done") \
+                or not op or "jit(" not in op.group(1) \
+                or re.match(r"\w+\[\]", shape):
+            continue
+        assert scope_of(op.group(1), SPMM_SCOPES) is not None, line[:200]
+        staged += 1
+    assert staged >= 4
+    mem = compiled.memory_analysis()
+    total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+             + mem.temp_size_in_bytes)
+    assert total < HBM_BYTES, f"{total / 1e9:.2f} GB does not fit one chip"
