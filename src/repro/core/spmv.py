@@ -6,7 +6,8 @@ to the format whose TPU access pattern matches it:
 
     banded        -> DIA   (streaming x windows; FD fast path)
     blocked       -> BELL  (dense 8x128 tiles; useful-byte gathers)
-    unstructured  -> CSR   (column-blocked scalar-prefetch kernel)
+    unstructured  -> CSR, segmented CSR or HYB, whichever runner
+                     gathers and merges the fewest device elements
 
 The decision machinery itself lives in `repro.plan` (compile-once:
 analyze -> reorder -> convert -> pre-padded kernel layout, frozen into a
@@ -93,7 +94,7 @@ def spmv_dense_jnp(a: jax.Array, x: jax.Array) -> jax.Array:
 # ---------------------------------------------------------------------------
 
 def auto_format(csr: CSR, report: structure.StructureReport | None = None,
-                reordering=None, threads: int = 1):
+                reordering=None):
     """Pick the TPU-friendly format for this matrix's structure.
 
     Thin client of `repro.plan`: the decision rule is
@@ -107,8 +108,9 @@ def auto_format(csr: CSR, report: structure.StructureReport | None = None,
     the format decision reflects the post-reorder structure -- an RCM'd
     scrambled-banded matrix becomes DIA-eligible again.  Pass the same
     reordering to `spmv` to multiply in the original row order.
-    `threads` biases dispersed unstructured matrices toward the
-    nnz-balanced segmented layout, exactly as plan compilation would.
+    An unstructured matrix takes the layout whose runner touches the
+    fewest device elements, exactly as plan compilation would; for the
+    segmented layout that container is the CSR itself.
     """
     from repro import plan as _plan
 
@@ -116,8 +118,7 @@ def auto_format(csr: CSR, report: structure.StructureReport | None = None,
         csr = reordering.apply(csr)
         report = None
     rep = report or structure.analyze(csr)
-    return _plan.convert_chosen(
-        csr, _plan.choose_format(rep, threads=threads))[1]
+    return _plan.convert_chosen(csr, _plan.choose_format(rep, csr))[1]
 
 
 def spmv(matrix, x: jax.Array, use_pallas: bool = False,

@@ -13,8 +13,9 @@ order so plan choice is deterministic across runs.  Each reordering
 candidate's *permuted access stream* is scored by the same models the
 telemetry/parallel subsystems report with, and its format is read off
 its permuted structure (DIA for recovered bands, BELL for block density,
-HYB/segmented-CSR for power-law nnz dispersion, CSR otherwise) — so what
-the predictor scored is exactly the stream that format will exploit.
+otherwise the CSR, segmented-CSR or HYB layout whose runner touches the
+fewest device elements in XLA, see `choose_format`) — so what the
+predictor scored is exactly the stream that format will exploit.
 Forcing `format=` skips the O(nnz) structure analysis altogether.
 
 Predictors (`predictor=`):
@@ -49,14 +50,20 @@ Predictors (`predictor=`):
 predictor-vs-oracle compile counters by.  Its `*_s` keys are the seconds
 of the compile's phase spans (`plan.reorder`, `plan.analyze`,
 `plan.predict`, `plan.convert`, `plan.prepare`, inside `plan.compile`).
+Its `xla_elements` key is the chosen layout's count of the device
+elements its runner gathers and merges per multiply (see `xla_elements`),
+and `xla_elements_by_format` holds every candidate's when the rule ranked
+them.
 """
 from __future__ import annotations
 
 from typing import Dict, Optional
 
+import numpy as np
+
 from repro.core import structure
 from repro.core.cache_model import SANDY_BRIDGE, MachineModel
-from repro.core.formats import BELL, CSR, DIA, ELL, HYB
+from repro.core.formats import BELL, CSR, DIA, ELL, HYB, hyb_auto_threshold
 from repro.kernels import _layout as kl
 from repro.spans import span, spanned
 
@@ -74,14 +81,9 @@ REPLAY_NNZ_MAX = 16384
 REORDER_MARGIN = 0.02
 
 
-# Power-law detection for the nnz-balanced formats: above this nnz/row
-# coefficient of variation an unstructured matrix routes to the hybrid
-# row split (R-MAT sits at 1.7-3.2 across 2^8..2^12; uniform random at
-# ~0.37, FD at 0.0).  Between SEG_MIN_CV and HYB_MIN_CV, a multithreaded
-# plan takes the segmented (merge) CSR layout: rows are dispersed enough
-# that row partitions imbalance but not enough to justify a row split.
-HYB_MIN_CV = 1.0
-SEG_MIN_CV = 0.5
+# The layouts `choose_format` ranks for an unstructured matrix; ties in
+# the ranking go to the earlier name.  Semiring plans add ELL.
+UNSTRUCTURED_FORMATS = ("csr", "csr-seg", "hyb")
 
 # Semiring plans need absorbing padding, which the dense-footprint
 # formats (DIA bands, BELL tiles) cannot express -- see graph.semiring.
@@ -91,29 +93,127 @@ SEMIRING_FORMATS = ("csr", "csr-seg", "ell", "hyb")
 # sampled rows' offsets, `convert_chosen` the whole matrix's.
 DIA_MAX_DIAGS = 64
 
+# Lanes every gathered and merged array of the unstructured layouts pads
+# to (the `pad_mult` of their `kernels._layout.prepare_*`).
+LANES = 128
 
-def choose_format(report, threads: int = 1,
-                  semiring_safe: bool = False) -> str:
-    """Format name for a structure report (the dispatch rule that used to
-    live inline in `core.spmv.auto_format`).
 
-    `threads` biases unstructured dispersion toward the nnz-balanced
-    segmented layout (row partitions imbalance at scale);
-    `semiring_safe` restricts the choice to absorbing-pad formats
-    (`SEMIRING_FORMATS`), with ELL replacing the dense-footprint picks.
+def _seg_elements(indptr: np.ndarray, lengths: np.ndarray,
+                  seg_len: int) -> int:
+    """Gathered slots plus merged partials of `prepare_csr_seg`'s
+    row-major stream: S segments of `seg_len` slots, each with a rank
+    window of the most distinct rows (the pad row included) that any one
+    segment touches."""
+    nnz = int(indptr[-1])
+    seg = kl.round_up(max(int(seg_len), 1), LANES)
+    n_segs = max(kl.ceil_div(nnz, seg), 1)
+    lo = np.arange(n_segs, dtype=np.int64) * seg
+    hi = np.minimum(lo + seg, nnz)
+    nonempty = lengths > 0
+    starts, ends = indptr[:-1][nonempty], indptr[1:][nonempty]
+    # non-empty rows that start before a segment's end and end after its
+    # start; a segment with padding ranks the pad row too
+    rows = (np.searchsorted(starts, hi, side="left")
+            - np.searchsorted(ends, lo, side="right"))
+    rows = rows + (lo + seg > nnz)
+    rwin = kl.round_up(int(rows.max()), LANES)
+    return n_segs * seg + n_segs * rwin
+
+
+def xla_elements(csr: CSR, formats, *, bm: int = 128, seg_len: int = 512,
+                 n_stripes: int = 1) -> Dict[str, int]:
+    """format -> device elements its runner touches in XLA per multiply,
+    for each of `formats`: the x slots it gathers plus the partials its
+    merge scatters, counted from the row lengths alone (the columns too
+    for a striped CSR), with the knobs `_prepare` builds the layout with.
+
+      csr      stripes x row blocks x the fullest cell, lane-padded; the
+               stripe reduce is a dense sum and scatters nothing
+      csr-seg  S x L slots plus S x rwin partials
+      hyb      the light slab's rows x lane-padded width, plus the heavy
+               stream's slots and partials, its rank window bounded by
+               the segment length and the heavy rows (the stream is
+               column-sorted, so its exact window needs the columns)
+      ell      rows x the lane-padded widest row
     """
+    indptr = np.asarray(csr.indptr, dtype=np.int64)
+    lengths = np.diff(indptr)
+    n_rows = int(csr.n_rows)
+    rows_pad = kl.round_up(max(n_rows, 1), bm)
+
+    def count(format_name: str) -> int:
+        if format_name == "csr":
+            n_blocks = kl.ceil_div(n_rows, bm)
+            if n_stripes == 1:
+                bounds = indptr[np.minimum(np.arange(n_blocks + 1) * bm,
+                                           n_rows)]
+                per_cell = np.diff(bounds)
+            else:
+                stripe_w = kl.round_up(kl.ceil_div(csr.n_cols, n_stripes),
+                                       LANES)
+                cols = np.asarray(csr.indices, dtype=np.int64)
+                blocks = np.repeat(np.arange(n_rows, dtype=np.int64) // bm,
+                                   lengths)
+                per_cell = np.bincount(cols // stripe_w * n_blocks + blocks,
+                                       minlength=n_stripes * n_blocks)
+            width = kl.round_up(max(int(per_cell.max(initial=0)), 1), LANES)
+            return n_stripes * n_blocks * width
+        if format_name == "csr-seg":
+            return _seg_elements(indptr, lengths, seg_len)
+        if format_name == "ell":
+            return rows_pad * kl.round_up(max(int(lengths.max(initial=0)), 1),
+                                          LANES)
+        if format_name == "hyb":
+            heavy = lengths > hyb_auto_threshold(lengths)
+            light = kl.round_up(max(int(lengths[~heavy].max(initial=0)), 1),
+                                LANES)
+            heavy_nnz = int(lengths[heavy].sum())
+            seg = kl.round_up(max(int(seg_len), 1), LANES)
+            n_segs = max(kl.ceil_div(heavy_nnz, seg), 1)
+            padded = n_segs * seg > heavy_nnz
+            rwin = kl.round_up(min(seg, int(heavy.sum()) + padded), LANES)
+            return rows_pad * light + n_segs * (seg + rwin)
+        raise ValueError(f"no XLA element count for format {format_name!r}")
+
+    return {f: count(f) for f in formats}
+
+
+def _rank(report, csr: CSR, semiring_safe: bool, *, bm: int, seg_len: int,
+          n_stripes: int):
+    """(format, {candidate: xla_elements}) for a structure report: DIA and
+    BELL for the structures they store densely, else the candidate whose
+    runner touches the fewest XLA elements (the counts are None when no
+    ranking ran)."""
     if not semiring_safe:
         if (report.kind == "banded"
                 and report.n_distinct_offsets <= DIA_MAX_DIAGS):
-            return "dia"
+            return "dia", None
         if report.kind == "blocked":
-            return "bell"
-    if report.kind == "unstructured":
-        if report.row_nnz_cv >= HYB_MIN_CV:
-            return "hyb"                # power-law: split the hub rows off
-        if threads > 1 and report.row_nnz_cv >= SEG_MIN_CV:
-            return "csr-seg"            # dispersed: balance by nonzeros
-    return "ell" if semiring_safe else "csr"
+            return "bell", None
+    if report.kind != "unstructured":
+        return ("ell" if semiring_safe else "csr"), None
+    names = UNSTRUCTURED_FORMATS + (("ell",) if semiring_safe else ())
+    counts = xla_elements(csr, names, bm=bm, seg_len=seg_len,
+                          n_stripes=n_stripes)
+    return min(counts, key=counts.get), counts
+
+
+def choose_format(report, csr: CSR, semiring_safe: bool = False, *,
+                  bm: int = 128, seg_len: int = 512,
+                  n_stripes: int = 1) -> str:
+    """Format name for a matrix and its structure report (the dispatch
+    rule behind `plan.compile` and `core.spmv.auto_format`).
+
+    Banded and blocked structure take DIA and BELL.  An unstructured
+    matrix takes the layout among `UNSTRUCTURED_FORMATS` whose runner
+    touches the fewest device elements in XLA per multiply
+    (`xla_elements`, counted from the row lengths with the layout knobs
+    `bm`, `seg_len`, `n_stripes`).  `semiring_safe` restricts the choice
+    to absorbing-pad formats (`SEMIRING_FORMATS`), with ELL ranked too
+    and replacing the dense-footprint picks.
+    """
+    return _rank(report, csr, semiring_safe, bm=bm, seg_len=seg_len,
+                 n_stripes=n_stripes)[0]
 
 
 def convert(csr: CSR, format_name: str, fill: float = 0.0):
@@ -234,16 +334,15 @@ def compile(matrix: CSR, *,                       # noqa: A001 (plan.compile)
             sample_rows: Optional[int] = 65536) -> SpmvPlan:
     """Compile a CSR matrix into a frozen `SpmvPlan`.
 
-    threads    target thread count the predictor scores contention at
+    threads    target thread count the predictor scores reorderings at
     mesh       a device mesh: build a row-sharded plan (`shard_map` ELL
                path) over `partition` (default `rowblock_equal`)
     reorder    'auto' (predictor picks none-vs-RCM) | 'none'/None | a
                strategy name/callable | a concrete Reordering
     format     force a storage format
                ('dia'|'bell'|'ell'|'csr'|'csr-seg'|'hyb'); default reads
-               it off each candidate's permuted structure -- power-law
-               dispersion (row_nnz_cv) routes to the nnz-balanced 'hyb'
-               and 'csr-seg' layouts, see `choose_format`
+               it off each candidate's permuted structure and row
+               lengths, see `choose_format`
     seg_len    nonzeros per segment for the 'csr-seg'/'hyb' layouts
     semiring   name (or `Semiring`) of the (⊕, ⊗) pair the plan executes
                under ('plus_times' default).  Non-plus-times plans are
@@ -317,17 +416,21 @@ def compile(matrix: CSR, *,                       # noqa: A001 (plan.compile)
     # runs and processes, keeping fingerprint-salted cache entries stable.
     fmt_by: Dict[str, str] = {}
     report_by: Dict[str, object] = {}
+    counts_by: Dict[str, Optional[Dict[str, int]]] = {}
     with span("plan.analyze", stats if format is None else None,
-              "analyze_s"):
+              "analyze_s") as analyzed:
         for label in sorted(cands):
             if format is not None:
                 fmt_by[label], report_by[label] = format, None
+                counts_by[label] = None
             else:
                 rep = structure.analyze(permuted_by[label],
                                         sample_rows=sample_rows)
                 report_by[label] = rep
-                fmt_by[label] = choose_format(rep, threads=threads,
-                                              semiring_safe=sr is not None)
+                fmt_by[label], counts_by[label] = _rank(
+                    rep, permuted_by[label], sr is not None, bm=bm,
+                    seg_len=seg_len, n_stripes=n_stripes)
+        analyzed.set(format=",".join(fmt_by[lab] for lab in sorted(cands)))
     ordered = sorted(cands, key=lambda lab: (fmt_by[lab], lab))
 
     if len(ordered) > 1:
@@ -406,6 +509,16 @@ def compile(matrix: CSR, *,                       # noqa: A001 (plan.compile)
                                                     fill=pad_value)
         else:
             container = convert(permuted, format_name, fill=pad_value)
+
+    counts = counts_by[chosen]
+    if counts is not None:
+        stats["xla_elements_by_format"] = counts
+    if counts is not None and format_name in counts:
+        stats["xla_elements"] = counts[format_name]
+    elif format_name in UNSTRUCTURED_FORMATS + ("ell",):
+        stats["xla_elements"] = xla_elements(
+            permuted, (format_name,), bm=bm, seg_len=seg_len,
+            n_stripes=n_stripes)[format_name]
 
     with span("plan.prepare", stats, "prepare_s"):
         prep = _prepare(container, format_name, bn=bn, bm=bm,

@@ -101,6 +101,10 @@ class span:
         self.seconds = 0.0
         self._annotation = jax.profiler.TraceAnnotation(name, **args)
 
+    def set(self, **args) -> None:
+        """Add `args` to the open annotation (for what the body found)."""
+        self._annotation.set_metadata(**args)
+
     def __enter__(self) -> "span":
         self._annotation.__enter__()
         self._t0 = time.perf_counter()

@@ -8,7 +8,7 @@ import pytest
 
 from repro import plan
 from repro.core import structure
-from repro.core.formats import BELL, CSR, DIA, HYB
+from repro.core.formats import BELL, CSR, DIA
 from repro.core.generators import (banded_matrix, fd_matrix, rmat_matrix,
                                    uniform_random_matrix)
 from repro.core.spmv import auto_format, spmv
@@ -64,20 +64,34 @@ def test_blocked_dispatches_to_bell():
 
 
 def test_power_law_dispatches_to_hyb():
-    """Power-law row lengths (high nnz CV) route to the hybrid row split:
-    hub rows go to the column-sorted heavy stream, the rest stay ELL."""
+    """Power-law row lengths no longer route to the hybrid row split:
+    HYB pads its light slab to 128 lanes over every row, so its runner
+    gathers far more slots than the flat segmented stream, and the plan
+    takes the layout that touches the fewest XLA elements."""
     csr = rmat_matrix(2048, seed=5)
     rep = structure.analyze(csr)
     assert rep.kind == "unstructured"
-    assert rep.row_nnz_cv >= 1.0        # what triggers the hyb pick
+    assert rep.row_nnz_cv >= 1.0        # what picked hyb before the count
+    counts = plan.compiler.xla_elements(
+        csr, plan.compiler.UNSTRUCTURED_FORMATS)
+    assert min(counts, key=counts.get) == "csr-seg"
+    assert counts["hyb"] > 10 * counts["csr-seg"]
+    assert plan.choose_format(rep, csr) == "csr-seg"
     fmt = auto_format(csr, rep)
-    assert isinstance(fmt, HYB)
+    assert fmt is csr                   # csr-seg's container is the CSR
     _assert_matches_dense(fmt, csr)
+    p = plan.compile(csr, reorder="none", predictor="none")
+    assert p.format_name == "csr-seg"
+    x = np.random.default_rng(42).normal(size=csr.n_cols).astype(np.float32)
+    want = np.asarray(csr.to_dense(), np.float64) @ x
+    np.testing.assert_allclose(np.asarray(p.execute(jnp.asarray(x))), want,
+                               rtol=2e-3, atol=2e-3)
 
 
 def test_flat_unstructured_stays_csr():
-    """Unstructured but near-uniform row lengths (low CV): no hub rows to
-    split off, so the dispatcher keeps CSR."""
+    """Unstructured but near-uniform row lengths: every 128-row block
+    holds about the same nonzeros, so the column-blocked CSR layout pads
+    least and the dispatcher keeps it."""
     csr = uniform_random_matrix(2048, nnz_per_row=8, seed=5)
     rep = structure.analyze(csr)
     assert rep.kind == "unstructured"
@@ -119,16 +133,20 @@ def test_fd_plans_to_dia_past_the_cache_line_window(n_rows):
                                rtol=1e-4, atol=1e-4)
 
 
-def test_sparse_off_diagonals_stay_csr():
-    """Few offsets but a thin fill: 20 off-main diagonals of two entries
-    each would pad 20 full-length rows of DIA for 40 nonzeros."""
-    n = 4096
+def _sparse_off_diagonals(n=4096) -> CSR:
+    """A main diagonal plus 20 off-main diagonals of two entries each."""
     offs = [o for o in range(-10, 11) if o]
     rows = np.concatenate([np.arange(n)] + [[100, 3000]] * len(offs))
     cols = np.concatenate([np.arange(n)] + [[100 + o, 3000 + o]
                                             for o in offs])
     vals = np.random.default_rng(1).uniform(0.5, 1.5, rows.size)
-    csr = CSR.from_coo(rows, cols, vals, n, n)
+    return CSR.from_coo(rows, cols, vals, n, n)
+
+
+def test_sparse_off_diagonals_stay_csr():
+    """Few offsets but a thin fill: 20 off-main diagonals of two entries
+    each would pad 20 full-length rows of DIA for 40 nonzeros."""
+    csr = _sparse_off_diagonals()
     rep = structure.analyze(csr)
     assert rep.n_distinct_offsets == 21 and rep.bandwidth_p95 <= 512
     assert rep.kind != "banded"
@@ -161,7 +179,8 @@ def test_diagonals_outside_the_sample_keep_csr():
 @pytest.mark.parametrize("gen,expected", [
     (lambda: fd_matrix(1024), DIA),
     (lambda: _blocked_matrix(), BELL),
-    (lambda: rmat_matrix(2048, seed=5), HYB),
+    # csr-seg: the segmented layout runs over the CSR container
+    pytest.param(lambda: rmat_matrix(2048, seed=5), CSR, id="rmat-csr-seg"),
     (lambda: uniform_random_matrix(2048, nnz_per_row=8, seed=5), CSR),
 ])
 def test_all_dispatch_paths_agree_with_dense(gen, expected):
@@ -169,3 +188,80 @@ def test_all_dispatch_paths_agree_with_dense(gen, expected):
     fmt = auto_format(csr)
     assert isinstance(fmt, expected)
     _assert_matches_dense(fmt, csr)
+
+
+def _one_per_row(n=256) -> CSR:
+    """A permutation matrix: one segment of 256 rows plus its pad row,
+    which widens the rank window past 256."""
+    cols = np.random.default_rng(3).permutation(n)
+    return CSR.from_coo(np.arange(n), cols, np.ones(n, np.float32), n, n)
+
+
+def _built_elements(prep, format_name: str) -> int:
+    """Gathered index slots plus merged row ids of a built layout."""
+    if format_name == "csr":
+        return prep.cols.size
+    if format_name == "ell":
+        return prep.idx.size
+    if format_name == "csr-seg":
+        return prep.cols.size + prep.row_ids.size
+    return (prep.light.idx.size + prep.heavy.cols.size
+            + prep.heavy.row_ids.size)
+
+
+@pytest.mark.parametrize("semiring_safe", [False, True],
+                         ids=["plus_times", "semiring"])
+@pytest.mark.parametrize("gen,n_stripes", [
+    (lambda: rmat_matrix(2 ** 11, seed=5), 1),
+    (lambda: rmat_matrix(2 ** 11, seed=5), 2),
+    (lambda: rmat_matrix(2 ** 14, seed=1), 1),
+    (lambda: uniform_random_matrix(2048, nnz_per_row=8, seed=5), 1),
+    (_sparse_off_diagonals, 1),
+    (_one_per_row, 1),
+], ids=["rmat-2p11", "rmat-2p11-2-stripes", "rmat-2p14", "uniform-8",
+        "sparse-off-diagonals", "one-per-row"])
+def test_xla_element_counts_match_built_layouts(gen, n_stripes,
+                                                semiring_safe):
+    """The count the plan ranks by, taken from the row lengths, is the
+    size of the index arrays (and merge row ids) `_prepare` builds.  The
+    one bound is HYB's heavy rank window: its stream is column-sorted, so
+    the count takes the segment length or the heavy rows, whichever is
+    fewer."""
+    from repro.plan import compiler as pc
+
+    csr = gen()
+    rep = structure.analyze(csr)
+    assert rep.kind == "unstructured"
+    pad = float("inf") if semiring_safe else 0.0
+    knobs = dict(bm=128, seg_len=512, n_stripes=n_stripes)
+    counts = pc.xla_elements(csr, pc.UNSTRUCTURED_FORMATS + ("ell",),
+                             **knobs)
+    for fmt in counts:
+        prep = pc._prepare(pc.convert(csr, fmt, fill=pad), fmt, bn=512,
+                           pad_value=pad, **knobs)
+        built = _built_elements(prep, fmt)
+        if fmt != "hyb":
+            assert counts[fmt] == built, fmt
+            continue
+        slots = prep.light.idx.size + prep.heavy.cols.size
+        rwin_bound = (counts[fmt] - slots) // prep.heavy.cols.shape[0]
+        assert prep.heavy.rwin <= rwin_bound <= prep.heavy.seg_len
+        assert counts[fmt] >= built
+    ranked = pc.UNSTRUCTURED_FORMATS + (("ell",) if semiring_safe else ())
+    best = min(ranked, key=counts.get)
+    assert plan.choose_format(rep, csr, semiring_safe, **knobs) == best
+
+
+def test_compile_records_xla_elements():
+    """`plan.compile` records each ranked candidate's count and the chosen
+    layout's; a forced format records its own count."""
+    csr = rmat_matrix(2 ** 11, seed=5)
+    p = plan.compile(csr, reorder="none", predictor="none", use_pallas=False)
+    by_format = p.compile_stats["xla_elements_by_format"]
+    assert set(by_format) == {"csr", "csr-seg", "hyb"}
+    assert p.format_name == min(by_format, key=by_format.get) == "csr-seg"
+    assert p.compile_stats["xla_elements"] == by_format["csr-seg"]
+    forced = plan.compile(csr, reorder="none", predictor="none",
+                          use_pallas=False, format="hyb")
+    assert "xla_elements_by_format" not in forced.compile_stats
+    assert forced.compile_stats["xla_elements"] == by_format["hyb"]

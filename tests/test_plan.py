@@ -123,7 +123,7 @@ def test_cached_execute_zero_work_bit_identical_rmat_4k(monkeypatch):
 
     cache = plan.PlanCache()
     # format pinned to csr: bit-identity against the per-call CSR path is
-    # the point here (auto would pick hyb for this matrix; csr-seg/hyb
+    # the point here (auto would pick csr-seg for this matrix; csr-seg/hyb
     # bit-identity to the CSR kernel is pinned by the property suite)
     opts = dict(reorder="none", predictor="analytic", threads=4,
                 format="csr")

@@ -124,11 +124,13 @@ def test_compile_stats_and_cache_stats_keep_their_keys():
     a = rmat_matrix(1 << 9, seed=3)
     auto = plan.compile(a, reorder="none")
     assert set(auto.compile_stats) == {"reorder_s", "analyze_s", "scoring",
-                                       "predict_s", "convert_s", "prepare_s"}
+                                       "predict_s", "convert_s", "prepare_s",
+                                       "xla_elements",
+                                       "xla_elements_by_format"}
     forced = plan.compile(a, reorder="none", format="csr")
     assert set(forced.compile_stats) == {"reorder_s", "scoring",
                                          "predict_s", "convert_s",
-                                         "prepare_s"}
+                                         "prepare_s", "xla_elements"}
     assert all(v >= 0 for k, v in forced.compile_stats.items()
                if k.endswith("_s"))
     cache = plan.PlanCache()
