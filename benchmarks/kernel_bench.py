@@ -16,7 +16,8 @@ from repro.core import traffic
 from repro.core.formats import BELL, CSR, DIA
 from repro.core.generators import banded_matrix, fd_matrix, rmat_matrix
 from repro.core.spmv import spmv_csr_jnp
-from repro.kernels import ops
+from repro.kernels import _layout as kl
+from repro.kernels.paged_attention import paged_attention
 
 from .common import emit, time_fn
 
@@ -38,25 +39,29 @@ def spmv_kernels(n: int = 1024) -> str:
 
         dia = DIA.from_csr(csr)
         if dia.n_diags <= 160:
-            y = ops.spmv_dia(dia, x, bn=128)
+            dia_prep = kl.prepare_dia(dia, bn=128)
+            y = kl.spmv_dia_prepared(dia_prep, x)
             rows.append(["dia", name, n, dia.n_diags, _err(y, y_ref),
-                         time_fn(lambda: ops.spmv_dia(dia, x, bn=128), iters=2),
+                         time_fn(lambda: kl.spmv_dia_prepared(dia_prep, x),
+                                 iters=2),
                          t_ref,
                          traffic.stream_policy(
                              csr, int(np.abs(np.asarray(dia.offsets)).max())
                          ).roofline_gflops])
 
         bell = BELL.from_csr(csr)
-        y = ops.spmv_bell(bell, x)
+        bell_prep = kl.prepare_bell(bell)
+        y = kl.spmv_bell_prepared(bell_prep, x)
         rows.append(["bell", name, n, bell.blocks_per_row, _err(y, y_ref),
-                     time_fn(lambda: ops.spmv_bell(bell, x), iters=2), t_ref,
+                     time_fn(lambda: kl.spmv_bell_prepared(bell_prep, x),
+                             iters=2), t_ref,
                      traffic.bell_policy(bell.density(), csr)
                      .roofline_gflops])
 
-        prep = ops.prepare_csr(csr, n_stripes=4)
-        y = ops.spmv_csr_prepared(prep, x)
+        prep = kl.prepare_csr(csr, n_stripes=4)
+        y = kl.spmv_csr_prepared(prep, x)
         rows.append(["csr_colblock", name, n, 4, _err(y, y_ref),
-                     time_fn(lambda: ops.spmv_csr_prepared(prep, x), iters=2), t_ref,
+                     time_fn(lambda: kl.spmv_csr_prepared(prep, x), iters=2), t_ref,
                      traffic.col_blocked_policy(csr, 4).roofline_gflops])
     return emit(rows, ["kernel", "matrix", "n", "param", "max_err",
                        "t_interp_s", "t_jnp_s", "v5e_roofline_gflops"],
@@ -100,10 +105,10 @@ def paged_attention_bench() -> str:
                              .reshape(bsz, mb).astype(np.int32))
         lengths = jnp.asarray(
             rng.integers(1, mb * block + 1, bsz).astype(np.int32))
-        got = ops.paged_attention(q, kp, vp, tables, lengths)
+        got = paged_attention(q, kp, vp, tables, lengths)
         want = kref.paged_attention_ref(q, kp, vp, tables, lengths)
         rows.append(["paged", bsz, h, block, mb, _err(got, want),
-                     time_fn(lambda: ops.paged_attention(
+                     time_fn(lambda: paged_attention(
                          q, kp, vp, tables, lengths), iters=2),
                      time_fn(lambda: kref.paged_attention_ref(
                          q, kp, vp, tables, lengths))])

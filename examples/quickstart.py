@@ -12,10 +12,10 @@
 import numpy as np
 import jax.numpy as jnp
 
-from repro.core import (analyze, auto_format, fd_matrix, rmat_matrix, spmv,
-                        traffic)
+from repro.core import analyze, fd_matrix, rmat_matrix, spmv, traffic
 from repro.core.cache_model import analytic_metrics
 from repro.core.formats import BELL
+from repro.plan import auto_format
 
 N = 1 << 14
 

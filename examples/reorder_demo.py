@@ -16,8 +16,9 @@ import numpy as np
 import jax.numpy as jnp
 
 from repro import reorder
-from repro.core import analyze, auto_format, banded_matrix, rmat_matrix, spmv
+from repro.core import analyze, banded_matrix, rmat_matrix, spmv
 from repro.core.structure import analyze_reorder
+from repro.plan import auto_format
 from repro.telemetry.hierarchy import HierarchySpec
 from repro.telemetry.report import reorder_gap_report
 from repro.telemetry.sweep import reorder_sweep

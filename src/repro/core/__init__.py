@@ -10,14 +10,14 @@ matrix-vector multiply performance:
   cache_model  Sandy Bridge L2/L3+prefetcher model -> the paper's 5 metrics
   traffic      TPU HBM<->VMEM movement model (hardware adaptation)
   partition    row-blocking (threads/chips) + column-blocking (VMEM cache)
-  spmv         structure-aware dispatcher + jnp reference kernels
+  spmv         jnp reference kernels + their dispatch by container
 """
 from . import cache_model, delta, formats, generators, partition, spmv, structure, traffic
 from .cache_model import SANDY_BRIDGE, CacheMetrics, MachineModel, analytic_metrics
 from .delta import EdgeDelta, csr_diff, csr_lookup
 from .formats import BELL, CSR, DIA, ELL, HYB
 from .generators import banded_matrix, fd_matrix, rmat_matrix, uniform_random_matrix
-from .spmv import auto_format, spmv
+from .spmv import spmv
 from .structure import StructureReport, analyze
 from .traffic import TPU_V5E, TPUModel
 
@@ -26,6 +26,6 @@ __all__ = [
     "structure", "traffic", "SANDY_BRIDGE", "CacheMetrics", "MachineModel",
     "analytic_metrics", "BELL", "CSR", "DIA", "ELL", "HYB", "EdgeDelta",
     "csr_diff", "csr_lookup", "banded_matrix",
-    "fd_matrix", "rmat_matrix", "uniform_random_matrix", "auto_format",
+    "fd_matrix", "rmat_matrix", "uniform_random_matrix",
     "analyze", "StructureReport", "TPU_V5E", "TPUModel",
 ]

@@ -6,7 +6,7 @@ module executes the same `RowPartition` across real devices with
 `shard_map`: every device runs the Pallas ELL kernel on its row slab
 (x replicated, like the threads sharing one x working set), and y comes
 back row-sharded.  On CPU the kernel runs in interpret mode, on TPU as
-compiled Mosaic — the same dispatch contract as `repro.kernels.ops`.
+compiled Mosaic (`repro.kernels.resolve_interpret`).
 
 Shard preparation is part of the matrix's execution plan:
 `spmv_row_sharded` fetches a row-sharded `SpmvPlan` from

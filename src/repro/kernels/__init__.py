@@ -10,9 +10,8 @@
   _layout          shared host-side layout prep: `prepare_*` (run once,
                    at plan-compile time, or for SpMM on a plan's first
                    `spmm`) + `spmv_*_prepared` / `spmm_prepared` (zero
-                   matrix-side work per call)
-  ops              per-call wrappers composing prepare + run, plus the
-                   attention entry points
+                   matrix-side work per call); `repro.plan` is their
+                   one caller on the SpMV path
   flash_attention  causal + sliding-window (banded) attention
   paged_attention  decode over block-table KV (BELL pattern on the cache)
 

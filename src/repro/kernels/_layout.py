@@ -1,15 +1,14 @@
 """Shared host-side layout preparation for the Pallas SpMV kernels.
 
-One home for the padding / stripe-splitting / row-blocking arithmetic that
-used to be copied between `kernels.ops` and the per-format kernel modules.
-Every format gets a `prepare_*` function that does ALL matrix-side work
-(padding, reshaping, stripe bucketing) once, returning a `Prepared*`
-container, and a `spmv_*_prepared` runner that performs zero matrix-side
-work per call -- only the per-call x pad/reshape plus the Pallas kernel.
+One home for the padding / stripe-splitting / row-blocking arithmetic of
+the per-format kernel modules.  Every format gets a `prepare_*` function
+that does ALL matrix-side work (padding, reshaping, stripe bucketing)
+once, returning a `Prepared*` container, and a `spmv_*_prepared` runner
+that performs zero matrix-side work per call -- only the per-call x
+pad/reshape plus the Pallas kernel.
 
 This split is what `repro.plan` builds on: `prepare_*` runs at plan-compile
-time, `spmv_*_prepared` is the amortized hot path.  The per-call wrappers in
-`kernels.ops` are now just `prepare_*` + `spmv_*_prepared` composed.
+time, `spmv_*_prepared` is the amortized hot path.
 
 Every op of a runner lies in one stage scope (`repro.spans.SCOPES`): the
 x pad and reshape in `spmv.x_prep`, the gather in `spmv.x_gather`, the

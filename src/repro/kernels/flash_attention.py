@@ -122,3 +122,17 @@ def flash_attention_pallas(q: jax.Array, k: jax.Array, v: jax.Array,
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
     )(q, k, v)
+
+
+def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
+                    causal: bool = True, window: int | None = None,
+                    interpret: bool | None = None) -> jax.Array:
+    """q/k/v: (batch, heads, seq, head_dim); GQA callers broadcast kv first."""
+    b, h, sq, d = q.shape
+    skv = k.shape[2]
+    qf = q.reshape(b * h, sq, d)
+    kf = k.reshape(b * h, skv, d)
+    vf = v.reshape(b * h, skv, d)
+    of = flash_attention_pallas(qf, kf, vf, causal=causal, window=window,
+                                interpret=interpret)
+    return of.reshape(b, h, sq, d)

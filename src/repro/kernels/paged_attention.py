@@ -128,3 +128,18 @@ def paged_attention_pallas(q, k_pool, v_pool, tables, lengths,
         ),
     )(tables.astype(jnp.int32), lengths.astype(jnp.int32), q, kp, vp)
     return out
+
+
+def paged_attention(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
+                    tables: jax.Array, lengths: jax.Array,
+                    interpret: bool | None = None) -> jax.Array:
+    """q: (B, H, hd); pools: (n_blocks, block, KVH, hd) with KVH | H;
+    tables: (B, max_blocks) int32; lengths: (B,) -> (B, H, hd)."""
+    b, h, hd = q.shape
+    kvh = k_pool.shape[2]
+    if kvh != h:                      # GQA: broadcast KV heads to H
+        g = h // kvh
+        k_pool = jnp.repeat(k_pool, g, axis=2)
+        v_pool = jnp.repeat(v_pool, g, axis=2)
+    return paged_attention_pallas(q, k_pool, v_pool, tables, lengths,
+                                  interpret=interpret)

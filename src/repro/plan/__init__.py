@@ -17,7 +17,7 @@ This package freezes that decision chain once per matrix:
   overlay      OverlaidPlan: a frozen plan + edge delta served warm
                (streaming matrices; staleness-budgeted re-plan)
   cache        PlanCache + the process-wide DEFAULT_CACHE behind the
-               thin-client call paths (core.spmv, distributed.spmv)
+               graph drivers and distributed.spmv
   costmodel    the learned candidate scorer (structural features ->
                predicted throughput) that replaces trace replay on the
                default compile path, plus its replay-labeled training
@@ -36,8 +36,8 @@ Quick use:
     plan.save_plan(p, "ckpt/")             # survives restart
 """
 from .cache import DEFAULT_CACHE, PlanCache, get_plan
-from .compiler import (REPLAY_NNZ_MAX, choose_format, compile, convert,
-                       convert_chosen, plan_for_container)
+from .compiler import (REPLAY_NNZ_MAX, auto_format, choose_format, compile,
+                       convert, convert_chosen)
 from .costmodel import (CostModel, default_model, fit_cost_model,
                         set_default_model)
 from .fingerprint import (chain_fingerprint, delta_fingerprint,
@@ -52,8 +52,9 @@ from .serial import (load_model, load_plan, model_from_state, model_state,
 compile_plan = compile
 
 __all__ = [
-    "SpmvPlan", "compile", "compile_plan", "plan_for_container",
-    "choose_format", "convert", "convert_chosen", "REPLAY_NNZ_MAX",
+    "SpmvPlan", "compile", "compile_plan",
+    "auto_format", "choose_format", "convert", "convert_chosen",
+    "REPLAY_NNZ_MAX",
     "PlanCache", "DEFAULT_CACHE", "get_plan",
     "CostModel", "fit_cost_model", "default_model", "set_default_model",
     "OverlaidPlan", "overlay", "overlay_eligible",

@@ -4,9 +4,9 @@ Keys are matrix fingerprints salted with the compile options that change
 the produced plan, so the cache is self-invalidating: mutate one stored
 value and the digest (hence the key) changes, and the stale plan simply
 stops being found and ages out of the LRU.  A module-level
-`DEFAULT_CACHE` backs the thin-client call paths (`core.spmv.spmv`,
-`distributed.spmv.spmv_row_sharded`), so repeated per-call traffic on
-the same matrix amortizes to one compile.
+`DEFAULT_CACHE` backs the graph drivers' plans (`graph.drivers`) and
+`distributed.spmv.spmv_row_sharded`, so repeated traffic on the same
+matrix amortizes to one compile.
 """
 from __future__ import annotations
 

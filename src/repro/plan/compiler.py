@@ -202,7 +202,7 @@ def choose_format(report, csr: CSR, semiring_safe: bool = False, *,
                   bm: int = 128, seg_len: int = 512,
                   n_stripes: int = 1) -> str:
     """Format name for a matrix and its structure report (the dispatch
-    rule behind `plan.compile` and `core.spmv.auto_format`).
+    rule behind `compile` and `auto_format`).
 
     Banded and blocked structure take DIA and BELL.  An unstructured
     matrix takes the layout among `UNSTRUCTURED_FORMATS` whose runner
@@ -246,6 +246,31 @@ def convert_chosen(csr: CSR, format_name: str, fill: float = 0.0):
         dia = DIA.from_csr(csr, max_diags=DIA_MAX_DIAGS)
         return ("dia", dia) if dia is not None else ("csr", csr)
     return format_name, convert(csr, format_name, fill=fill)
+
+
+def auto_format(csr: CSR, report: structure.StructureReport | None = None,
+                reordering=None):
+    """Pick the TPU-friendly format for this matrix's structure.
+
+    One-shot: the decision rule is `choose_format` and the conversion
+    `convert_chosen` -- compile an `SpmvPlan` instead to also freeze the
+    kernel layout, or `compile(csr, predictor='auto')` to let the
+    learned cost model pick the reordering too.
+
+    With `reordering` (a `repro.reorder.Reordering`), the permutation is
+    applied first and the structure re-analyzed on the permuted matrix, so
+    the format decision reflects the post-reorder structure -- an RCM'd
+    scrambled-banded matrix becomes DIA-eligible again.  Pass the same
+    reordering to `core.spmv.spmv` to multiply in the original row order.
+    An unstructured matrix takes the layout whose runner touches the
+    fewest device elements, exactly as plan compilation would; for the
+    segmented layout that container is the CSR itself.
+    """
+    if reordering is not None:
+        csr = reordering.apply(csr)
+        report = None
+    rep = report or structure.analyze(csr)
+    return convert_chosen(csr, choose_format(rep, csr))[1]
 
 
 def _prepare(container, format_name: str, *, bn: int, bm: int,
@@ -550,18 +575,3 @@ def _compile_sharded(fp, permuted, reordering, report, mesh, partition, *,
         csr=permuted if keep_csr else None, threads=threads,
         use_pallas=True, interpret=interpret, predicted=predicted,
         chosen=chosen, compile_stats=stats, mesh=mesh)
-
-
-def plan_for_container(matrix, interpret: Optional[bool] = None) -> SpmvPlan:
-    """Minimal plan for an ALREADY-CONVERTED container (no analysis, no
-    reordering decision — the caller chose the format): just the one-time
-    kernel layout prep.  This is what `core.spmv.spmv` caches so repeated
-    per-call dispatch stops re-padding the matrix."""
-    names = {DIA: "dia", BELL: "bell", ELL: "ell", CSR: "csr", HYB: "hyb"}
-    format_name = names[type(matrix)]
-    prep = _prepare(matrix, format_name, bn=512, bm=128, n_stripes=1)
-    return SpmvPlan(
-        fingerprint=matrix_fingerprint(matrix), format_name=format_name,
-        container=matrix, prep=prep,
-        csr=matrix if isinstance(matrix, CSR) else None,
-        interpret=interpret, chosen="container")

@@ -10,9 +10,8 @@ the per-call x pad, and the kernel itself.
 
 Repeated-traffic surfaces built on a plan:
 
-  * `execute(x)`        one multiply, bit-identical to the per-call
-                        `core.spmv.spmv(fmt, x, use_pallas=True)` path
-                        (same prepared layout, same kernel);
+  * `execute(x)`        one multiply: the format's `_layout` runner over
+                        the layout prepared at compile time;
   * `spmm(X)`           k vectors at once, features last: Y = A @ X for X
                         of shape (n_cols, k), through the row-gather
                         Pallas kernel over a layout built on the first
@@ -39,7 +38,8 @@ from typing import Any, Dict, Optional
 import jax
 import jax.numpy as jnp
 
-from repro.core.formats import BELL, CSR, DIA, ELL, HYB
+from repro.core.formats import CSR
+from repro.core.spmv import JNP_KERNELS
 from repro.kernels import _layout as kl
 from repro.spans import span
 
@@ -52,16 +52,6 @@ RUNNERS = {
     "csr-seg": kl.spmv_csr_seg_prepared,
     "hyb": kl.spmv_hyb_prepared,
 }
-
-
-def _jnp_kernels():
-    """Container type -> vectorized jnp reference kernel (late import:
-    `core.spmv` is a thin client of this package)."""
-    from repro.core.spmv import (spmv_bell_jnp, spmv_csr_jnp, spmv_dia_jnp,
-                                 spmv_ell_jnp, spmv_hyb_jnp)
-
-    return {CSR: spmv_csr_jnp, ELL: spmv_ell_jnp,
-            BELL: spmv_bell_jnp, DIA: spmv_dia_jnp, HYB: spmv_hyb_jnp}
 
 
 @dataclasses.dataclass
@@ -182,7 +172,7 @@ class SpmvPlan:
         if sr is not None:
             from repro.graph.semiring import spmv_semiring_jnp
             return lambda xv: spmv_semiring_jnp(container, xv, sr)
-        kern = _jnp_kernels()[type(container)]
+        kern = JNP_KERNELS[type(container)]
         return lambda xv: kern(container, xv)
 
     # -- repeated-traffic surfaces ------------------------------------------

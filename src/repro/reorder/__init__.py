@@ -3,7 +3,7 @@
 The software-side counterpart of the telemetry subsystem's §V hardware
 mechanisms: instead of adding victim caches / stream buffers to tolerate
 an unstructured x-access stream, permute the matrix so the stream becomes
-structured in the first place, then let `core.spmv.auto_format` re-decide
+structured in the first place, then let `plan.auto_format` re-decide
 the storage format on the reordered matrix.
 
   types        Reordering (row/col perms + inverses + provenance), compose

@@ -4,9 +4,9 @@ The paper shows SpMV performance is set by the *structure* of the x-access
 stream; PR 1 attacked the unstructured case from the hardware side (victim
 caches, stream buffers).  These strategies are the software-side answer:
 permute the matrix so the stream the kernel actually issues becomes
-sequential/reused -- i.e. prefetchable -- and `auto_format` can re-decide
-the storage format afterwards (an RCM'd scrambled-banded matrix becomes
-DIA-eligible again).
+sequential/reused -- i.e. prefetchable -- and `plan.auto_format` can
+re-decide the storage format afterwards (an RCM'd scrambled-banded
+matrix becomes DIA-eligible again).
 
   rcm          reverse Cuthill-McKee bandwidth reduction (pure-numpy BFS)
   degree_sort  rows ordered by nnz (absorbs partition.sort_rows_by_nnz)

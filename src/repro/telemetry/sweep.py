@@ -80,8 +80,8 @@ def _matrix(kind: str, n: int, seed: int = 0) -> CSR:
 
 # Sweep plans pin a permuted CSR plus a memoized full address trace each
 # (several MB per 2^16 cell), so they get their own small cache rather
-# than crowding `plan.DEFAULT_CACHE` (whose entries back live spmv
-# traffic).  Lazily constructed to keep module import light.
+# than crowding `plan.DEFAULT_CACHE` (whose entries back the graph
+# drivers' live traffic).  Lazily constructed to keep module import light.
 _PLAN_CACHE = None
 
 

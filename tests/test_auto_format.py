@@ -11,7 +11,8 @@ from repro.core import structure
 from repro.core.formats import BELL, CSR, DIA
 from repro.core.generators import (banded_matrix, fd_matrix, rmat_matrix,
                                    uniform_random_matrix)
-from repro.core.spmv import auto_format, spmv
+from repro.core.spmv import spmv
+from repro.plan import auto_format
 
 
 def _blocked_matrix(n=1024, n_blocks=12, seed=0) -> CSR:

@@ -6,13 +6,12 @@ import numpy as np
 import pytest
 
 from repro import plan
-from repro.core.formats import CSR, ELL
+from repro.core.formats import CSR
 from repro.core.generators import fd_matrix, rmat_matrix
-from repro.core.spmv import spmv
 from repro.graph import (MIN_PLUS, OR_AND, PLUS_TIMES, SEMIRINGS, bfs,
                          connected_components, pagerank,
                          spmv_semiring_jnp, sssp, transpose_csr)
-from repro.kernels import ops as kops
+from repro.kernels import _layout as kl
 
 N = 256
 
@@ -117,12 +116,13 @@ def test_plus_times_semiring_bit_identical_to_existing_pallas():
     m = rmat_matrix(N, seed=4)
     x = jnp.asarray(np.random.default_rng(1).normal(size=N)
                     .astype(np.float32))
+    pairs = {"ell": (kl.prepare_ell, kl.spmv_ell_prepared),
+             "csr": (kl.prepare_csr, kl.spmv_csr_prepared)}
     for fmt in ("ell", "csr"):
-        container = plan.convert(m, fmt)
-        base = spmv(container, x, use_pallas=True)
-        via_semiring = {
-            "ell": kops.spmv_ell, "csr": kops.spmv_csr,
-        }[fmt](container, x, semiring=PLUS_TIMES)
+        prepare, run = pairs[fmt]
+        prep = prepare(plan.convert(m, fmt))
+        base = run(prep, x)
+        via_semiring = run(prep, x, semiring=PLUS_TIMES)
         np.testing.assert_array_equal(np.asarray(base),
                                       np.asarray(via_semiring))
         p = plan.compile(m, semiring="plus_times", format=fmt,
@@ -340,17 +340,35 @@ def test_graph_sweep_runs_connected_components():
     assert {p.kind for p in pts} == {"fd", "rmat"}
 
 
-def test_spmv_ell_rejects_non_absorbing_container():
-    """An ELL built with the default fill=0.0 must be refused under
-    min-plus (its padding would read as real weight-0 edges), while the
-    correctly built container is accepted."""
-    m = rmat_matrix(128, seed=3)
-    x = jnp.ones((128,), jnp.float32)
-    with pytest.raises(ValueError, match="fill=semiring.pad_value"):
-        kops.spmv_ell(ELL.from_csr(m), x, semiring=MIN_PLUS)
-    y = kops.spmv_ell(ELL.from_csr(m, fill=MIN_PLUS.pad_value), x,
-                      semiring=MIN_PLUS)
-    assert y.shape == (128,)
+def _padding_probe_graph():
+    """Empty rows, rows of one or two edges, a hub row that outgrows the
+    rest, and an explicit weight-0.0 edge into vertex 0: the slots a
+    padded layout must tell apart from its own padding."""
+    rows = [0, 0, 2, 4, 4, 6]
+    cols = [1, 2, 0, 0, 3, 5]
+    vals = [3.0, 1.0, 0.0, 2.0, 4.0, 1.0]
+    hub = list(range(1, 31))
+    rows += [5] * len(hub)
+    cols += hub
+    vals += [float(1 + c % 4) for c in hub]
+    return CSR.from_coo(rows, cols, vals, 32, 32)
+
+
+@pytest.mark.parametrize("fmt", ["ell", "hyb", "csr", "csr-seg"])
+def test_min_plus_plan_padding_is_absorbing(fmt):
+    """A min-plus plan pads with +inf wherever it builds its layout, so no
+    padding slot reads as a weight-0 edge to vertex 0, while a real
+    weight-0 edge into vertex 0 still counts."""
+    m = _padding_probe_graph()
+    x = np.arange(5.0, 5.0 + m.n_cols)            # x[0] = 5 is the smallest
+    p = plan.compile(m, semiring="min_plus", format=fmt, reorder="none",
+                     predictor="none")
+    got = np.asarray(p.execute(jnp.asarray(x, jnp.float32)))
+
+    w = np.where(_nz_mask(m), np.asarray(m.to_dense(), np.float64), np.inf)
+    want = (w + x[None, :]).min(axis=1)
+    assert want[2] == 5.0 and np.isinf(want[1])
+    np.testing.assert_array_equal(got, want.astype(np.float32))
 
 
 def test_compile_rejects_unregistered_semiring_instance():
@@ -367,13 +385,11 @@ def test_compile_rejects_unregistered_semiring_instance():
 
 
 def test_core_spmv_pagerank_delegates_with_legacy_semantics():
-    """The compatibility wrapper must reproduce the historical
+    """PageRank on the transpose reproduces the historical
     column-stochastic iteration exactly (same math, fixed iterations)."""
-    from repro.core.spmv import pagerank as legacy_pagerank
-
     m = rmat_matrix(256, seed=9)
     n = m.n_rows
-    got = np.asarray(legacy_pagerank(m, n_iters=16))
+    got = np.asarray(pagerank(transpose_csr(m), tol=0, max_iters=16).values)
 
     ip, ci = np.asarray(m.indptr), np.asarray(m.indices)
     col_deg = np.bincount(ci, minlength=n).astype(np.float64)

@@ -28,7 +28,7 @@ from repro import plan
 from repro.core.formats import CSR, ELL, HYB
 from repro.core.generators import fd_matrix, rmat_matrix
 from repro.graph.semiring import SEMIRINGS
-from repro.kernels import ops as kops
+from repro.kernels import _layout as kl
 
 # Formats the plan compiler can be forced to, by semiring compatibility.
 PLUS_TIMES_FORMATS = ("csr", "csr-seg", "ell", "hyb", "dia", "bell")
@@ -180,8 +180,7 @@ def test_segment_boundary_carry_is_exact(n, seed, seg_len):
     csr = _int_csr("single-dense-row", n, seed)
     x = _int_x(csr.n_cols, seed)
     ref = _dense_ref(csr, x)
-    y = np.asarray(kops.spmv_csr_seg(csr, jnp.asarray(x), seg_len=seg_len,
-                                     interpret=True))
+    y = _execute(csr, x, "csr-seg", seg_len=seg_len)
     assert np.array_equal(y, ref)
     y_hyb = _execute(csr, x, "hyb", seg_len=seg_len)
     assert np.array_equal(y_hyb, ref)
@@ -328,7 +327,8 @@ def test_zero_row_ell_layout():
               indices=jnp.zeros((0,), jnp.int32),
               indptr=jnp.zeros((1,), jnp.int32), n_rows=0, n_cols=4)
     ell = ELL.from_csr(csr)
-    y = kops.spmv_ell(ell, jnp.ones((4,), jnp.float32), interpret=True)
+    y = kl.spmv_ell_prepared(kl.prepare_ell(ell), jnp.ones((4,), jnp.float32),
+                             interpret=True)
     assert y.shape == (0,)
 
 
@@ -341,28 +341,6 @@ def test_out_of_range_sources_rejected():
             bfs(csr, bad)
         with pytest.raises(ValueError, match="out of range"):
             sssp(csr, bad)
-
-
-@pytest.mark.parametrize("container", ["ell", "hyb"])
-def test_non_absorbing_padding_refused(container):
-    """An ELL/HYB slab padded with (0.0, col 0) must be refused under a
-    semiring whose absorbing element is not 0.0 — those slots would read
-    as real weight-0 edges to vertex 0."""
-    csr = _int_csr("empty-rows", 16, seed=3)
-    x = jnp.asarray(_int_x(csr.n_cols, seed=3))
-    sr = SEMIRINGS["min_plus"]
-    if container == "ell":
-        bad = ELL.from_csr(csr, fill=0.0)
-        with pytest.raises(ValueError, match="absorbing"):
-            kops.spmv_ell(bad, x, interpret=True, semiring=sr)
-        good = ELL.from_csr(csr, fill=sr.pad_value)
-        kops.spmv_ell(good, x, interpret=True, semiring=sr)
-    else:
-        bad = HYB.from_csr(csr, fill=0.0)
-        with pytest.raises(ValueError, match="absorbing"):
-            kops.spmv_hyb(bad, x, interpret=True, semiring=sr)
-        good = HYB.from_csr(csr, fill=sr.pad_value)
-        kops.spmv_hyb(good, x, interpret=True, semiring=sr)
 
 
 def test_hyb_routes_hub_rows_to_heavy():

@@ -1,7 +1,8 @@
 """Per-kernel allclose sweeps against the pure-jnp oracles (interpret=True).
 
-Each Pallas kernel sweeps shapes and dtypes per the deliverable contract.
-Sizes stay modest: interpret mode executes the grid in Python.
+Each Pallas kernel sweeps shapes and dtypes per the deliverable contract,
+through the `_layout` prepare/run pair a plan replays.  Sizes stay
+modest: interpret mode executes the grid in Python.
 """
 import jax.numpy as jnp
 import numpy as np
@@ -9,7 +10,8 @@ import pytest
 
 from repro.core.formats import BELL, CSR, DIA
 from repro.core.generators import banded_matrix, fd_matrix, rmat_matrix
-from repro.kernels import ops, ref
+from repro.kernels import _layout as kl
+from repro.kernels import ref
 from repro.kernels.flash_attention import flash_attention_pallas
 from repro.kernels.spmv_dia import spmv_dia_pallas
 
@@ -45,7 +47,7 @@ def test_dia_kernel_shapes(n, bn):
     csr = fd_matrix(n)
     dia = DIA.from_csr(csr)
     x = _x(n)
-    got = ops.spmv_dia(dia, x, bn=bn)
+    got = kl.spmv_dia_prepared(kl.prepare_dia(dia, bn=bn), x)
     want = ref.spmv_dia_ref(dia.data, dia.offsets, x)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=1e-5, atol=1e-5)
@@ -86,7 +88,7 @@ def test_bell_kernel_vs_oracle(n, seed):
     csr = rmat_matrix(n, seed=seed)
     bell = BELL.from_csr(csr)
     x = _x(n, seed=seed)
-    got = ops.spmv_bell(bell, x)
+    got = kl.spmv_bell_prepared(kl.prepare_bell(bell), x)
     want = np.asarray(csr.to_dense()) @ np.asarray(x)
     np.testing.assert_allclose(np.asarray(got), want, rtol=1e-4, atol=1e-4)
 
@@ -117,7 +119,7 @@ def test_bell_bf16_inputs_fp32_accum():
                   n_rows=bell.n_rows, n_cols=bell.n_cols,
                   bm=bell.bm, bn=bell.bn, blocks_per_row=bell.blocks_per_row)
     x = _x(256, dtype=np.float32, seed=3).astype(jnp.bfloat16)
-    got = ops.spmv_bell(bell16, x)
+    got = kl.spmv_bell_prepared(kl.prepare_bell(bell16), x)
     want = ref.spmv_bell_ref(bell.data, bell.block_cols,
                              jnp.pad(x.astype(jnp.float32),
                                      (0, bell.bn * (-(-256 // bell.bn)) - 256)))
@@ -135,7 +137,7 @@ def test_ell_kernel_vs_dense(n, seed):
     csr = rmat_matrix(n, seed=seed)
     ell = ELL.from_csr(csr)
     x = _x(n, seed=seed)
-    got = ops.spmv_ell(ell, x)
+    got = kl.spmv_ell_prepared(kl.prepare_ell(ell), x)
     want = np.asarray(csr.to_dense()) @ np.asarray(x)
     np.testing.assert_allclose(np.asarray(got), want, rtol=1e-4, atol=1e-4)
 
@@ -147,20 +149,22 @@ def test_ell_kernel_banded_and_blocksizes():
     x = _x(384, seed=11)
     want = np.asarray(csr.to_dense()) @ np.asarray(x)
     for bm in (64, 128, 256):
-        got = ops.spmv_ell(ell, x, bm=bm)
+        got = kl.spmv_ell_prepared(kl.prepare_ell(ell, bm=bm), x)
         np.testing.assert_allclose(np.asarray(got), want,
                                    rtol=1e-4, atol=1e-4)
 
 
 def test_ell_pallas_routed_from_dispatcher():
-    """use_pallas=True must run the ELL kernel, not fall back to jnp."""
-    from repro.core.formats import ELL
+    """An ELL plan must run the ELL kernel's runner, not fall back to jnp,
+    and match the jnp reference."""
+    from repro import plan
     from repro.core.spmv import spmv
     csr = rmat_matrix(256, seed=3)
-    ell = ELL.from_csr(csr)
+    p = plan.compile(csr, format="ell", reorder="none", predictor="none")
+    assert p._runner(None)[0] is kl.spmv_ell_prepared
     x = _x(256, seed=4)
-    got = spmv(ell, x, use_pallas=True)
-    want = spmv(ell, x, use_pallas=False)
+    got = p.execute(x)
+    want = spmv(p.container, x)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=1e-4, atol=1e-4)
 
@@ -173,17 +177,17 @@ def test_ell_pallas_routed_from_dispatcher():
 def test_csr_colblock_stripes(n_stripes):
     csr = rmat_matrix(512, seed=4)
     x = _x(512, seed=5)
-    got = ops.spmv_csr(csr, x, n_stripes=n_stripes)
+    got = kl.spmv_csr_prepared(kl.prepare_csr(csr, n_stripes=n_stripes), x)
     want = np.asarray(csr.to_dense()) @ np.asarray(x)
     np.testing.assert_allclose(np.asarray(got), want, rtol=1e-4, atol=1e-4)
 
 
 def test_csr_prepared_reuse():
     csr = fd_matrix(256)
-    prep = ops.prepare_csr(csr, n_stripes=2)
+    prep = kl.prepare_csr(csr, n_stripes=2)
     for seed in range(3):
         x = _x(256, seed=seed)
-        got = ops.spmv_csr_prepared(prep, x)
+        got = kl.spmv_csr_prepared(prep, x)
         want = np.asarray(csr.to_dense()) @ np.asarray(x)
         np.testing.assert_allclose(np.asarray(got), want,
                                    rtol=1e-4, atol=1e-4)
@@ -191,11 +195,11 @@ def test_csr_prepared_reuse():
 
 def test_csr_padded_ref_matches_kernel_layout():
     csr = rmat_matrix(256, seed=6)
-    prep = ops.prepare_csr(csr, n_stripes=2)
+    prep = kl.prepare_csr(csr, n_stripes=2)
     xp = jnp.pad(_x(256, seed=7),
                  (0, 2 * prep.stripe_w - 256)).reshape(2, prep.stripe_w)
     want = ref.spmv_csr_padded_ref(prep.vals, prep.cols, prep.rowin, xp)
-    got = ops.spmv_csr_prepared(prep, _x(256, seed=7))
+    got = kl.spmv_csr_prepared(prep, _x(256, seed=7))
     np.testing.assert_allclose(np.asarray(got),
                                np.asarray(want)[:256], rtol=1e-4, atol=1e-4)
 
@@ -257,17 +261,18 @@ def _empty_csr(n=8):
 
 def test_all_kernels_handle_empty_matrix():
     """nnz=0 regression: the DIA path used to crash on a zero-diagonal
-    band (empty scalar-prefetch operand); every per-call wrapper must
+    band (empty scalar-prefetch operand); every kernel runner must
     return exact zeros."""
     from repro.core.formats import ELL
 
     m = _empty_csr(8)
     x = _x(8)
     for name, got in [
-        ("dia", ops.spmv_dia(DIA.from_csr(m), x)),
-        ("bell", ops.spmv_bell(BELL.from_csr(m), x)),
-        ("ell", ops.spmv_ell(ELL.from_csr(m), x)),
-        ("csr", ops.spmv_csr(m, x)),
+        ("dia", kl.spmv_dia_prepared(kl.prepare_dia(DIA.from_csr(m)), x)),
+        ("bell", kl.spmv_bell_prepared(kl.prepare_bell(BELL.from_csr(m)),
+                                       x)),
+        ("ell", kl.spmv_ell_prepared(kl.prepare_ell(ELL.from_csr(m)), x)),
+        ("csr", kl.spmv_csr_prepared(kl.prepare_csr(m), x)),
     ]:
         np.testing.assert_array_equal(np.asarray(got), np.zeros(8),
                                       err_msg=name)
@@ -279,7 +284,8 @@ def test_single_row_kernels_match_dense():
     m = CSR.from_coo([0, 0, 0], [0, 2, 5], [1.0, 2.0, 3.0], 1, 6)
     x = _x(6, seed=3)
     want = np.asarray(m.to_dense()) @ np.asarray(x)
-    for got in (ops.spmv_csr(m, x), ops.spmv_ell(ELL.from_csr(m), x)):
+    for got in (kl.spmv_csr_prepared(kl.prepare_csr(m), x),
+                kl.spmv_ell_prepared(kl.prepare_ell(ELL.from_csr(m)), x)):
         np.testing.assert_allclose(np.asarray(got), want, rtol=1e-6)
 
 
@@ -293,11 +299,14 @@ def test_semiring_kernel_min_plus_matches_reference(fmt):
     m = rmat_matrix(256, seed=6)
     x = jnp.asarray(np.abs(np.random.default_rng(0).normal(size=256))
                     .astype(np.float32))
+    pad = MIN_PLUS.pad_value
     if fmt == "ell":
-        container = ELL.from_csr(m, fill=MIN_PLUS.pad_value)
-        got = ops.spmv_ell(container, x, semiring=MIN_PLUS)
+        container = ELL.from_csr(m, fill=pad)
+        got = kl.spmv_ell_prepared(kl.prepare_ell(container, pad_value=pad),
+                                   x, semiring=MIN_PLUS)
     else:
-        got = ops.spmv_csr(m, x, semiring=MIN_PLUS)
+        got = kl.spmv_csr_prepared(kl.prepare_csr(m, pad_value=pad), x,
+                                   semiring=MIN_PLUS)
 
     dense = np.asarray(m.to_dense(), np.float64)
     nz = np.zeros(dense.shape, bool)
@@ -318,9 +327,10 @@ def test_semiring_plus_times_arg_is_bit_identical():
     m = rmat_matrix(256, seed=8)
     ell = ELL.from_csr(m)
     x = _x(256, seed=9)
+    ell_prep, csr_prep = kl.prepare_ell(ell), kl.prepare_csr(m)
     np.testing.assert_array_equal(
-        np.asarray(ops.spmv_ell(ell, x)),
-        np.asarray(ops.spmv_ell(ell, x, semiring=PLUS_TIMES)))
+        np.asarray(kl.spmv_ell_prepared(ell_prep, x)),
+        np.asarray(kl.spmv_ell_prepared(ell_prep, x, semiring=PLUS_TIMES)))
     np.testing.assert_array_equal(
-        np.asarray(ops.spmv_csr(m, x)),
-        np.asarray(ops.spmv_csr(m, x, semiring=PLUS_TIMES)))
+        np.asarray(kl.spmv_csr_prepared(csr_prep, x)),
+        np.asarray(kl.spmv_csr_prepared(csr_prep, x, semiring=PLUS_TIMES)))

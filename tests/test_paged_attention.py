@@ -4,7 +4,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.kernels import ops, ref
+from repro.kernels import ref
+from repro.kernels.paged_attention import paged_attention
 from repro.serve import BlockAllocator, PoolConfig
 
 
@@ -27,7 +28,7 @@ def _setup(bsz=3, h=4, hd=32, n_blocks=16, block=8, max_blocks=4, seed=0,
 ])
 def test_paged_attention_sweep(bsz, h, hd, block):
     q, kp, vp, tables, lengths = _setup(bsz=bsz, h=h, hd=hd, block=block)
-    got = ops.paged_attention(q, kp, vp, tables, lengths)
+    got = paged_attention(q, kp, vp, tables, lengths)
     want = ref.paged_attention_ref(q, kp, vp, tables, lengths)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=2e-4, atol=2e-4)
@@ -40,7 +41,7 @@ def test_paged_attention_gqa_broadcast():
     vp = jnp.asarray(rng.normal(size=(8, 8, 2, 32)).astype(np.float32))
     tables = jnp.asarray(np.array([[0, 1], [2, 3]], np.int32))
     lengths = jnp.asarray(np.array([12, 9], np.int32))
-    got = ops.paged_attention(q, kp, vp, tables, lengths)
+    got = paged_attention(q, kp, vp, tables, lengths)
     kpb = jnp.repeat(kp, 4, axis=2)
     vpb = jnp.repeat(vp, 4, axis=2)
     want = ref.paged_attention_ref(q, kpb, vpb, tables, lengths)
@@ -50,7 +51,7 @@ def test_paged_attention_gqa_broadcast():
 
 def test_paged_attention_bf16():
     q, kp, vp, tables, lengths = _setup(seed=2)
-    got = ops.paged_attention(q.astype(jnp.bfloat16),
+    got = paged_attention(q.astype(jnp.bfloat16),
                               kp.astype(jnp.bfloat16),
                               vp.astype(jnp.bfloat16), tables, lengths)
     want = ref.paged_attention_ref(q, kp, vp, tables, lengths)
@@ -63,7 +64,7 @@ def test_paged_attention_respects_lengths():
     its output (the kernel never reads unowned/overflow positions)."""
     q, kp, vp, tables, lengths = _setup(seed=3)
     lengths = jnp.asarray(np.array([5, 9, 17], np.int32))
-    out1 = ops.paged_attention(q, kp, vp, tables, lengths)
+    out1 = paged_attention(q, kp, vp, tables, lengths)
     # poison everything past each sequence's length within its blocks
     kp2 = np.asarray(kp).copy()
     block = kp2.shape[1]
@@ -73,7 +74,7 @@ def test_paged_attention_respects_lengths():
         for j, blk in enumerate(tb[b]):
             lo = max(ln - j * block, 0)
             kp2[blk, lo:] = 1e3
-    out2 = ops.paged_attention(q, jnp.asarray(kp2), vp, tables, lengths)
+    out2 = paged_attention(q, jnp.asarray(kp2), vp, tables, lengths)
     np.testing.assert_allclose(np.asarray(out1), np.asarray(out2),
                                rtol=1e-5, atol=1e-5)
 
@@ -91,7 +92,7 @@ def test_paged_attention_with_allocator_tables():
     q = jnp.asarray(rng.normal(size=(2, 4, 32)).astype(np.float32))
     kp = jnp.asarray(rng.normal(size=(16, 8, 4, 32)).astype(np.float32))
     vp = jnp.asarray(rng.normal(size=(16, 8, 4, 32)).astype(np.float32))
-    got = ops.paged_attention(q, kp, vp, tables, lengths)
+    got = paged_attention(q, kp, vp, tables, lengths)
     want = ref.paged_attention_ref(q, kp, vp, tables, lengths)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=2e-4, atol=2e-4)

@@ -1,5 +1,5 @@
 """Plan/executor pipeline: fingerprints, cache correctness, zero-work
-cached execution, bit-identity with the per-call path, serialization."""
+cached execution, bit-identity with the `_layout` runner, serialization."""
 import time
 
 import jax.numpy as jnp
@@ -116,14 +116,14 @@ def _install_work_counters(monkeypatch, counts):
 def test_cached_execute_zero_work_bit_identical_rmat_4k(monkeypatch):
     """R-MAT 2^12: a cached plan execute performs zero structure analysis,
     reordering, format conversion, or layout padding, and its result is
-    bit-identical to the per-call `spmv(..., use_pallas=True)` path."""
+    bit-identical to the CSR `_layout` pair run on the same matrix."""
     csr = rmat_matrix(2 ** 12, seed=0)
     x = _x(csr.n_cols, seed=5)
-    y_percall = spmv(csr, x, use_pallas=True, interpret=True)
+    y_percall = kl.spmv_csr_prepared(kl.prepare_csr(csr), x, interpret=True)
 
     cache = plan.PlanCache()
-    # format pinned to csr: bit-identity against the per-call CSR path is
-    # the point here (auto would pick csr-seg for this matrix; csr-seg/hyb
+    # format pinned to csr: bit-identity against the CSR runner is the
+    # point here (auto would pick csr-seg for this matrix; csr-seg/hyb
     # bit-identity to the CSR kernel is pinned by the property suite)
     opts = dict(reorder="none", predictor="analytic", threads=4,
                 format="csr")
@@ -144,12 +144,14 @@ def test_reordered_plan_matches_reordered_spmv_bitwise():
     from repro.reorder import Reordering
     scrambled = Reordering(row_perm=perm, col_perm=perm).apply(base)
 
-    p = plan.compile(scrambled, reorder="rcm", predictor="none")
+    p = plan.compile(scrambled, reorder="rcm", predictor="none",
+                     format="csr")
     assert p.reordering is not None
     x = _x(512, seed=2)
     y_plan = p.execute(x, interpret=True)
-    y_ref = spmv(p.container, x, use_pallas=True, interpret=True,
-                 reordering=p.reordering)
+    y_ref = p.reordering.restore_y(kl.spmv_csr_prepared(
+        kl.prepare_csr(p.container), p.reordering.permute_x(x),
+        interpret=True))
     assert np.array_equal(np.asarray(y_plan), np.asarray(y_ref))
     # and both equal the unpermuted multiply up to float tolerance
     np.testing.assert_allclose(np.asarray(y_plan),
@@ -213,36 +215,6 @@ def test_warm_execute_amortizes_compile():
         warm_ts.append(time.perf_counter() - t0)
     warm = float(np.median(warm_ts))
     assert warm < cold / 2, f"warm {warm:.4f}s vs cold {cold:.4f}s"
-
-
-# ---------------------------------------------------------------------------
-# spmv thin client
-# ---------------------------------------------------------------------------
-
-def test_spmv_pallas_routes_through_default_cache():
-    csr = rmat_matrix(256, seed=9)
-    x = _x(256)
-    y1 = spmv(csr, x, use_pallas=True, interpret=True)
-    before = plan.DEFAULT_CACHE.stats()
-    y2 = spmv(csr, x, use_pallas=True, interpret=True)
-    after = plan.DEFAULT_CACHE.stats()
-    assert after["hits"] == before["hits"] + 1
-    assert np.array_equal(np.asarray(y1), np.asarray(y2))
-
-
-def test_spmv_still_works_under_jit_tracing():
-    # tracer containers cannot be fingerprinted; spmv must fall back
-    import jax
-
-    dia = DIA.from_csr(fd_matrix(256))
-    x = _x(256)
-
-    @jax.jit
-    def f(d, xv):
-        return spmv(d, xv, use_pallas=True, interpret=True)
-
-    np.testing.assert_allclose(np.asarray(f(dia, x)),
-                               np.asarray(spmv(dia, x)), rtol=1e-4, atol=1e-4)
 
 
 # ---------------------------------------------------------------------------
@@ -432,8 +404,7 @@ def test_empty_matrix_plan_executes_to_zeros():
     x = jnp.ones((8,), jnp.float32)
     p = plan.compile(m)
     np.testing.assert_array_equal(np.asarray(p.execute(x)), np.zeros(8))
-    np.testing.assert_array_equal(
-        np.asarray(spmv(m, x, use_pallas=True)), np.zeros(8))
+    np.testing.assert_array_equal(np.asarray(spmv(m, x)), np.zeros(8))
     # every forced format survives nnz=0 too
     for fmt in ("dia", "bell", "ell", "csr"):
         pf = plan.compile(m, format=fmt, reorder="none", predictor="none")
@@ -452,8 +423,7 @@ def test_single_row_matrix_plan_and_spmv():
     x = jnp.asarray([1.0, 10.0, 100.0], jnp.float32)
     p = plan.compile(m)
     np.testing.assert_array_equal(np.asarray(p.execute(x)), [201.0])
-    np.testing.assert_array_equal(
-        np.asarray(spmv(m, x, use_pallas=True)), [201.0])
+    np.testing.assert_array_equal(np.asarray(spmv(m, x)), [201.0])
 
 
 def test_invalidate_accepts_mutated_matrix():

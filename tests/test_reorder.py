@@ -8,8 +8,9 @@ import pytest
 from repro import reorder
 from repro.core.formats import CSR, DIA
 from repro.core.generators import banded_matrix, fd_matrix, rmat_matrix
-from repro.core.spmv import auto_format, spmv
+from repro.core.spmv import spmv
 from repro.core.structure import analyze, analyze_reorder
+from repro.plan import auto_format
 
 N = 256
 
@@ -145,15 +146,16 @@ def test_analyze_reorder_reports_improvement():
 
 
 def test_pallas_ops_accept_reordering():
-    from repro.kernels import ops as kops
+    from repro import plan
 
     m = _int_valued(rmat_matrix(N, seed=12))
     x = jnp.asarray(np.random.default_rng(13).integers(
         0, 8, size=N).astype(np.float32))
     r = reorder.cache_block(m)
     y_ref = np.asarray(spmv(m, x))
-    y = np.asarray(kops.spmv_csr(r.apply(m), x, interpret=True,
-                                 reordering=r))
+    p = plan.compile(m, reorder=r, format="csr", predictor="none")
+    assert p.reordering is r
+    y = np.asarray(p.execute(x, interpret=True))
     np.testing.assert_array_equal(y, y_ref)
 
 

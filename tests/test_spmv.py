@@ -1,10 +1,14 @@
 """Dispatcher + composite analytics (paper §I motivation)."""
+import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
-from repro.core.formats import BELL, CSR, DIA, HYB
+from repro.core.formats import BELL, CSR, DIA, ELL, HYB
 from repro.core.generators import banded_matrix, fd_matrix, rmat_matrix
-from repro.core.spmv import auto_format, pagerank, power_iteration, spmv
+from repro.core.spmv import power_iteration, spmv
+from repro.graph import pagerank, transpose_csr
+from repro.plan import auto_format
 
 
 def test_auto_format_banded_goes_dia():
@@ -17,14 +21,29 @@ def test_auto_format_unstructured_goes_csr_bell_or_hyb():
 
 
 def test_spmv_pallas_path_matches_jnp():
+    from repro import plan
+
     csr = fd_matrix(256)
     x = jnp.asarray(np.random.default_rng(0).normal(size=256)
                     .astype(np.float32))
-    fmt = auto_format(csr)
-    y_pallas = spmv(fmt, x, use_pallas=True, interpret=True)
+    p = plan.compile(csr, reorder="none", predictor="none")
+    assert type(p.container) is type(auto_format(csr))
+    y_pallas = p.execute(x, interpret=True)
     y_jnp = spmv(csr, x)
     np.testing.assert_allclose(np.asarray(y_pallas), np.asarray(y_jnp),
                                rtol=1e-4, atol=1e-4)
+
+
+@pytest.mark.parametrize("container", [CSR, ELL, HYB, BELL, DIA])
+def test_spmv_jnp_dispatch_traces_under_jit(container):
+    """The jnp dispatch is traceable: a traced container multiplies to
+    what the eager call gives."""
+    csr = rmat_matrix(256, seed=9)
+    m = csr if container is CSR else container.from_csr(csr)
+    x = jnp.asarray(np.random.default_rng(1).normal(size=256)
+                    .astype(np.float32))
+    np.testing.assert_allclose(np.asarray(jax.jit(spmv)(m, x)),
+                               np.asarray(spmv(m, x)), rtol=1e-5, atol=1e-5)
 
 
 def test_power_iteration_converges_on_spd():
@@ -41,9 +60,6 @@ def test_power_iteration_converges_on_spd():
 
 
 def test_pagerank_is_distribution():
-    r = pagerank(rmat_matrix(512), n_iters=16)
+    r = pagerank(transpose_csr(rmat_matrix(512)), tol=0, max_iters=16).values
     assert float(jnp.sum(r)) == pytest.approx(1.0, abs=0.05)
     assert float(jnp.min(r)) >= 0.0
-
-
-import pytest  # noqa: E402  (used above)
